@@ -82,6 +82,8 @@ def main() -> None:
                          "with the run's key metrics + git sha "
                          "(empty string disables)")
     args = ap.parse_args()
+    from repro.device import ensure_compile_cache
+    ensure_compile_cache()
     n = 120 if args.fast else 240
     t0 = time.time()
     print("name,us_per_call,derived")

@@ -117,10 +117,12 @@ def fit_dpo(params_raw, cfg: EncoderConfig, pref_data: dict,
     opt = chain_clip(adamw(lr, weight_decay=0.0), 1.0)
     state = opt.init(params_raw)
 
+    # the frozen reference is an argument, not a closure: a captured
+    # tree would be baked into the executable as constants
     @jax.jit
-    def step_fn(params, state, step, batch):
+    def step_fn(params, ref, state, step, batch):
         loss, grads = jax.value_and_grad(
-            lambda p: dpo_loss(p, ref_params, cfg, batch, beta))(params)
+            lambda p: dpo_loss(p, ref, cfg, batch, beta))(params)
         updates, state = opt.update(grads, state, params, step)
         return apply_updates(params, updates), state, loss
 
@@ -133,7 +135,7 @@ def fit_dpo(params_raw, cfg: EncoderConfig, pref_data: dict,
             if it >= steps:
                 break
             batch = {k: jnp.asarray(v[bidx]) for k, v in pref_data.items()}
-            params_raw, state, loss = step_fn(params_raw, state,
+            params_raw, state, loss = step_fn(params_raw, ref_params, state,
                                               jnp.asarray(it), batch)
             losses.append(float(loss))
             it += 1

@@ -73,6 +73,7 @@ from repro.core.engine import (AdaParseEngine, BatchTelemetry, EngineConfig,
 from repro.core.shm import (CoordinatorShmTransport, ShmArena,  # noqa: F401
                             ShmRef)
 from repro.data.pipeline import Prefetcher
+from repro.device import refuse_chip_children
 
 TRANSPORTS = ("shm", "pickle")
 
@@ -707,6 +708,7 @@ class ProcessWorkerPool:
                  probe_cfg=None, image_degraded=False, text_degraded=False,
                  backend_specs: tuple = ()):
         self._validate_xcfg(xcfg)
+        refuse_chip_children("process")
         transport = getattr(xcfg, "transport", "shm")
         if transport not in TRANSPORTS:
             raise ValueError(f"unknown transport {transport!r}; choose "

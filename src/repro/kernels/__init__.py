@@ -12,5 +12,8 @@ jit wrapper w/ backend dispatch), ref.py (exact host oracle), and —
 where a block size is worth sweeping — autotune.py on the shared
 ``autotune_common`` harness, with winners persisted fleet-wide through
 ``tuning_store`` (``serve.py --tuning-dir``).
-Validated with interpret=True on CPU; real-TPU is the lowering target.
+Every kernel takes ``interpret`` with no default: the ops pass
+``interpret=(backend != "tpu")``, CPU tests pass ``interpret=True``, and
+tests/test_tpu_compile.py compiles the main-path kernels
+(budget_route, fast_features, ngram_score) for a described v5e chip.
 """

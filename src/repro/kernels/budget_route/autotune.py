@@ -1,9 +1,10 @@
 """Block-size autotuning for the budget_route kernel.
 
-Sweeps ``block_n`` candidates at a given (N, D, capacity) shape through
-the shared ``autotune_common`` harness, and caches the winner per
-(shape, backend, device-mode) so ``budget_route`` picks it up
-transparently on later calls. The CI sweep runs in interpret mode
+Sweeps ``block_n`` (compacted rows gathered per program) candidates at
+a given (N, D, capacity) shape through the shared ``autotune_common``
+harness, and caches the winner per (shape, backend, device-mode) so
+``budget_route`` picks it up transparently on later calls. The CI
+sweep runs in interpret mode
 (functional timing signal only — it exercises the grid/BlockSpec
 plumbing at every candidate); the real-device sweep is gated behind
 ``device=True`` (CLI ``--device``) and refuses to run off-TPU, because
@@ -33,8 +34,10 @@ from repro.kernels.autotune_common import TuneRecord  # re-export
 from repro.kernels.budget_route.kernel import budget_route_kernel
 
 KERNEL_NAME = "budget_route"
-DEFAULT_BLOCK_N = 256
-DEFAULT_CANDIDATES = (128, 256, 512, 1024, 2048)
+DEFAULT_BLOCK_N = 64
+# each program's output block is (block_n, 1, D): every value tiles;
+# the cap keeps the double-buffered block (8 sublanes per row) in VMEM
+DEFAULT_CANDIDATES = (8, 16, 32, 64, 128)
 # the production routing shape (configs.py "adaparse-router" route_64k)
 ROUTE_64K = (65536, 512)
 

@@ -1,21 +1,29 @@
-"""Pallas-TPU fused budget-route: threshold-select + compact-gather.
+"""Pallas-TPU budget-route: threshold-select + compact-gather.
 
 The AdaParse scheduling op (App. C): given per-document improvement
 scores and the α-budget threshold τ (the ⌊αk⌋-th largest score, computed
 by a cheap top-k outside), select and *compact* the routed documents'
 token rows into a dense (capacity, D) buffer for the expensive parser —
-one pass over the batch, no host round-trip, no full sort.
+no host round-trip, no full sort.
 
 Selection rule (shared with ref.py and scheduler.plan_batch): rows with
 score > τ are always selected — by definition of τ at most capacity−1
-exist — while ties *at* τ consume a tie budget (capacity − |{s > τ}|,
-computed outside) first-come in row order. A strictly better row is
-therefore never displaced by a tie, and host/device pick identical sets.
+exist — while ties *at* τ consume a tie budget (capacity − |{s > τ}|)
+first-come in row order. A strictly better row is therefore never
+displaced by a tie, and host/device pick identical sets.
 
-Grid: (n_blocks,) sequential over score blocks. A 2-cell SMEM scratch
-carries the running output offset and the running tie count across
-blocks; within a block the write position is offset +
-exclusive-cumsum(keep). Rows are written with dynamic stores.
+The rule's two exclusive prefix sums (tie rank, write position) are
+O(N) XLA scans in the same jitted program (``ref.route_slots``): the
+TPU's Pallas lowering has no cumsum, and a per-row scalar read of a
+vector mask does not lower either. The kernel is the compaction: the
+slot → source-row table rides in SMEM (scalar prefetch) and each
+program DMAs ``block_n`` selected rows straight from HBM into its
+output block, zeroing the empty slots, so the gather reads only the
+⌊αN⌋ selected rows. Rows travel as (1, 1, D) slabs of an (N, 1, D)
+view, so every DMA is a legal tile whatever the row index (a (1, D)
+slice of a 2-D HBM array must start on an 8-row tile boundary), with D
+padded up to whole 128-lane tiles (the reduced router's rows are 64
+wide); the view costs XLA one relayout copy of the token matrix.
 """
 from __future__ import annotations
 
@@ -26,94 +34,67 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.budget_route.ref import route_slots
 
-def _route_kernel(tau_ref, tiecap_ref, scores_ref, tokens_ref, out_ref,
-                  idx_ref, count_ref, state_smem, *, block_n: int,
-                  capacity: int, n_total: int):
-    bi = pl.program_id(0)
+LANES = 128
 
-    @pl.when(bi == 0)
-    def _init():
-        state_smem[0] = 0               # rows written so far
-        state_smem[1] = 0               # ties at tau consumed so far
-        count_ref[0] = 0
-        idx_ref[...] = jnp.full_like(idx_ref, -1)
 
-    tau = tau_ref[0]
-    tie_cap = tiecap_ref[0]
-    scores = scores_ref[...]                        # (block_n,)
-    rows = bi * block_n + jax.lax.iota(jnp.int32, block_n)
-    in_range = rows < n_total
-    gt = (scores > tau) & in_range
-    eq = (scores == tau) & in_range
-    eq_i = eq.astype(jnp.int32)
-    tie_rank = state_smem[1] + jnp.cumsum(eq_i) - eq_i
-    keep = gt | (eq & (tie_rank < tie_cap))
-    inc = keep.astype(jnp.int32)
-    pos_in_block = jnp.cumsum(inc) - inc            # exclusive cumsum
-    base = state_smem[0]
-    positions = base + pos_in_block
+def _gather_kernel(idx_ref, tok_hbm, out_ref, sem, *, block_n: int):
+    base = pl.program_id(0) * block_n
 
-    def write_row(i, _):
-        @pl.when(keep[i] & (positions[i] < capacity))
-        def _w():
-            out_ref[pl.dslice(positions[i], 1), :] = tokens_ref[
-                pl.dslice(i, 1), :]
-            idx_ref[pl.dslice(positions[i], 1)] = rows[i][None]
-        return 0
+    def start(r, c):
+        src = jnp.maximum(idx_ref[base + r], 0)
+        pltpu.make_async_copy(tok_hbm.at[pl.ds(src, 1)],
+                              out_ref.at[pl.ds(r, 1)], sem).start()
+        return c
 
-    jax.lax.fori_loop(0, block_n, write_row, 0)
-    state_smem[0] = base + jnp.sum(inc)
-    state_smem[1] = state_smem[1] + jnp.sum((eq & keep).astype(jnp.int32))
+    def wait(r, c):
+        # every copy is one (1, 1, D) row on the same semaphore
+        pltpu.make_async_copy(tok_hbm.at[pl.ds(0, 1)],
+                              out_ref.at[pl.ds(r, 1)], sem).wait()
+        return c
 
-    @pl.when(bi == pl.num_programs(0) - 1)
-    def _finish():
-        count_ref[0] = jnp.minimum(state_smem[0], capacity)
+    def clear(r, c):
+        @pl.when(idx_ref[base + r] < 0)
+        def _():
+            out_ref[pl.ds(r, 1)] = jnp.zeros((1,) + out_ref.shape[1:],
+                                             out_ref.dtype)
+        return c
+
+    jax.lax.fori_loop(0, block_n, start, 0)
+    jax.lax.fori_loop(0, block_n, wait, 0)
+    jax.lax.fori_loop(0, block_n, clear, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "block_n",
                                              "interpret"))
 def budget_route_kernel(scores, tokens, tau, *, capacity: int,
-                        block_n: int = 256, interpret=True):
+                        interpret: bool, block_n: int = 64):
     """scores (N,) f32; tokens (N, D); tau scalar threshold.
 
     Returns (routed (capacity, D), idx (capacity,) int32 source rows
-    (-1 = empty), count scalar int32).
-    """
+    (-1 = empty), count scalar int32). ``block_n`` is the number of
+    compacted rows each program gathers (the autotunable knob)."""
     n, d_tok = tokens.shape
-    block_n = min(block_n, n)
     scores = scores.astype(jnp.float32)
     tau = jnp.asarray(tau, jnp.float32)
-    # tie budget: slots left after every strictly-greater row is taken
-    tie_cap = capacity - jnp.sum(scores > tau).astype(jnp.int32)
-    pad = (-n) % block_n
-    if pad:
-        scores = jnp.pad(scores, (0, pad), constant_values=-jnp.inf)
-        tokens = jnp.pad(tokens, ((0, pad), (0, 0)))
-    n_pad = n + pad
-    grid = (n_pad // block_n,)
-    kern = functools.partial(_route_kernel, block_n=block_n,
-                             capacity=capacity, n_total=n)
-    out, idx, count = pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # tau
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # tie budget
-            pl.BlockSpec((block_n,), lambda i: (i,)),        # scores
-            pl.BlockSpec((block_n, d_tok), lambda i: (i, 0)),  # tokens
-        ],
-        out_specs=[
-            pl.BlockSpec((capacity, d_tok), lambda i: (0, 0)),
-            pl.BlockSpec((capacity,), lambda i: (0,)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((capacity, d_tok), tokens.dtype),
-            jax.ShapeDtypeStruct((capacity,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
+    idx, count = route_slots(scores, tau, capacity=capacity)
+    block_n = max(1, min(int(block_n), capacity))
+    cap_pad = -(-capacity // block_n) * block_n
+    idx_pad = jnp.pad(idx, (0, cap_pad - capacity), constant_values=-1)
+    d_pad = -(-d_tok // LANES) * LANES
+    rows = jnp.pad(tokens.reshape(n, 1, d_tok),
+                   ((0, 0), (0, 0), (0, d_pad - d_tok)))
+    out = pl.pallas_call(
+        functools.partial(_gather_kernel, block_n=block_n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,                        # slot -> row
+            grid=(cap_pad // block_n,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],  # tokens (HBM)
+            out_specs=pl.BlockSpec((block_n, 1, d_pad),
+                                   lambda i, idx: (i, 0, 0)),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct((cap_pad, 1, d_pad), tokens.dtype),
         interpret=interpret,
-    )(tau[None], tie_cap[None], scores, tokens)
-    return out, idx, count[0]
+    )(idx_pad, rows)
+    return out[:capacity, 0, :d_tok], idx, count

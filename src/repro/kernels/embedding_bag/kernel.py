@@ -14,7 +14,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 
 def _bag_kernel(ids_ref, w_ref, table_ref, out_ref, *, bag: int,
@@ -45,7 +44,7 @@ def _bag_kernel(ids_ref, w_ref, table_ref, out_ref, *, bag: int,
 @functools.partial(jax.jit, static_argnames=("combiner", "block_b",
                                              "interpret"))
 def embedding_bag_kernel(table, ids, weights, *, combiner: str = "sum",
-                         block_b: int = 64, interpret=True):
+                         block_b: int = 64, interpret: bool):
     """table (R, D); ids (B, L) int32; weights (B, L) f32 -> (B, D)."""
     b, bag = ids.shape
     r, d = table.shape
@@ -63,7 +62,7 @@ def embedding_bag_kernel(table, ids, weights, *, combiner: str = "sum",
         in_specs=[
             pl.BlockSpec((block_b, bag), lambda i: (i, 0)),
             pl.BlockSpec((block_b, bag), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),       # full table
+            pl.BlockSpec(memory_space=pl.ANY),          # full table
         ],
         out_specs=pl.BlockSpec((block_b, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b + pad, d), table.dtype),

@@ -1,7 +1,8 @@
 """Comparison-block autotuning for the fast_features kernel.
 
-The kernel's distinct-token scan compares ``block_l`` candidate columns
-per step; the sweep times power-of-two candidates at a packed
+The kernel's distinct-token scan extracts each comparison column from a
+``block_l``-lane slice of the stream; the sweep times power-of-two
+candidates (multiples of the 128 lanes, so every one tiles) at a packed
 (width, max_len) shape through the shared ``autotune_common`` harness
 and persists the winner when a tuning store is configured. Because
 ``pack_routing_batch`` quantizes widths to powers of two, a fleet sees

@@ -92,7 +92,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
                                              "block_k", "interpret"))
 def flash_attention_kernel(q, k, v, *, causal=True, window=None,
-                           block_q=128, block_k=128, interpret=True):
+                           block_q=128, block_k=128, interpret: bool):
     """q (B, Sq, H, D); k, v (B, Skv, Hk, D); H % Hk == 0.
 
     Grouped heads are folded into the batch dim: each (b, kv_head, group)

@@ -22,11 +22,12 @@ import numpy as np
 
 from repro.kernels import autotune_common, tuning_store
 from repro.kernels.autotune_common import TuneRecord  # re-export
-from repro.kernels.ngram_score.kernel import ngram_bleu_kernel
+from repro.kernels.ngram_score.kernel import SUBLANES, ngram_bleu_kernel
 
 KERNEL_NAME = "ngram_score"
-DEFAULT_BLOCK_B = 1
-DEFAULT_CANDIDATES = (1, 2, 4, 8)
+DEFAULT_BLOCK_B = 8
+# compiled blocks are (block_b, L): block_b must fill the 8 sublanes
+DEFAULT_CANDIDATES = (8, 16, 32)
 
 __all__ = ["TuneRecord", "autotune_ngram_bleu", "tuned_block_b",
            "ensure_tuned", "clear_cache", "DEFAULT_BLOCK_B",
@@ -36,7 +37,7 @@ __all__ = ["TuneRecord", "autotune_ngram_bleu", "tuned_block_b",
 def tuned_block_b(b: int, max_len: int, max_n: int = 4,
                   device: bool | None = None) -> int:
     """The cached/stored winner for this probe shape, or the default
-    (one document per program)."""
+    (eight documents per program)."""
     return autotune_common.tuned_value(
         KERNEL_NAME, (b, max_len, max_n), DEFAULT_BLOCK_B, device=device)
 
@@ -63,7 +64,10 @@ def _make_run(b: int, max_len: int, max_n: int, device: bool, seed: int):
 
 
 def _clamp_candidates(candidates, b: int) -> tuple[int, ...]:
-    return tuple(sorted({max(1, min(int(c), b)) for c in candidates}))
+    # the kernel pads the batch to a block multiple: never clamp a
+    # candidate below the batch rounded up to whole sublane groups
+    b_up = -(-b // SUBLANES) * SUBLANES
+    return tuple(sorted({max(1, min(int(c), b_up)) for c in candidates}))
 
 
 def autotune_ngram_bleu(b: int, max_len: int, *, max_n: int = 4,

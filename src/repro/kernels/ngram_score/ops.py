@@ -26,7 +26,7 @@ def ngram_bleu(ref, hyp, ref_len, hyp_len, *, max_n: int = 4,
     """ref, hyp: (B, max_len) padded int id arrays; ref_len, hyp_len:
     (B,) true lengths. Returns (B,) float64 per-document BLEU.
     ``block_b=None`` consults the per-shape autotune cache/store on the
-    kernel path (default: one doc per program for untuned shapes)."""
+    kernel path (default: eight docs per program for untuned shapes)."""
     ref = np.asarray(ref)
     hyp = np.asarray(hyp)
     if ref.shape != hyp.shape or ref.ndim != 2:

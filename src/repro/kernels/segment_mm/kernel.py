@@ -57,7 +57,7 @@ def _segmm_kernel(xg_ref, w_ref, dst_ref, out_ref, *, block_e: int,
 @functools.partial(jax.jit, static_argnames=("n_nodes", "block_e",
                                              "interpret"))
 def segment_matmul_kernel(x_gathered, w, dst_sorted, *, n_nodes: int,
-                          block_e: int = 256, interpret=True):
+                          block_e: int = 256, interpret: bool):
     """x_gathered (E, Din) = x[src] pre-gathered; w (Din, Dout);
     dst_sorted (E,) int32 ascending. Returns (n_nodes, Dout) fp32."""
     e, d_in = x_gathered.shape

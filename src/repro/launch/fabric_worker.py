@@ -219,7 +219,11 @@ def spawn_loopback(addr: tuple[str, int], *,
                    fingerprint: dict | None = None) -> mp.process.BaseProcess:
     """Launch one fabric worker as a local spawn-context process
     dialing ``addr`` (a fresh interpreter, like the process runtime's
-    children — no inherited JAX state)."""
+    children — no inherited JAX state). Refused when this process holds
+    a TPU (``device.refuse_chip_children``)."""
+    from repro.device import refuse_chip_children
+
+    refuse_chip_children("loopback fabric")
     host, port = addr
     ctx = mp.get_context("spawn")
     p = ctx.Process(target=_loopback_main, args=(host, port, fingerprint),

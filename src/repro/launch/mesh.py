@@ -8,31 +8,13 @@ this module does not touch jax device state. The ``pod`` axis is
 data-parallel by default (the paper's workload is document-parallel);
 ``pipeline=True`` retags it for 1F1B pipelining (distributed/pipeline.py).
 
-``make_mesh`` is the version-compat entry point: newer jax releases grew
-``jax.sharding.AxisType`` + an ``axis_types=`` kwarg on ``jax.make_mesh``
-(explicit-sharding meshes), older ones have neither. Everything in the
-repo (and the tests) builds meshes through this shim so both work.
+``make_mesh`` builds every mesh in the repo with explicit ``Auto`` axis
+types (``jax.sharding.AxisType``), so sharding stays compiler-chosen.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-
-
-def axis_types_kwargs(n_axes: int) -> dict:
-    """``{"axis_types": (Auto,) * n}`` when the installed jax supports it,
-    else ``{}`` (pre-AxisType releases default to auto sharding anyway)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    try:
-        params = inspect.signature(jax.make_mesh).parameters
-    except (TypeError, ValueError):          # pragma: no cover
-        return {}
-    if "axis_types" not in params:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -43,7 +25,7 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_mesh(shape, axes):
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **axis_types_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 # v5e hardware constants (roofline)

@@ -4,8 +4,12 @@
         [--variant ft|llm] [--nodes 1]
 
 Builds the corpus, trains the CLS-I/II linear stages (and, for the LLM
-variant, SFT+DPO post-trains a reduced SciBERT router), then runs the
-engine over the test split and reports Table-1-style metrics + throughput.
+variant, SFT+DPO post-trains the SciBERT-class router from seeded
+weights), then runs the engine over the test split and reports
+Table-1-style metrics + throughput. ``--router-config`` picks the
+router's encoder: ``reduced`` (the default; 2 layers, d=32, cheap on a
+CPU) or ``published`` (``adaparse-router``: 12 layers, d=768, 512
+tokens — the width the chip runs).
 With ``--nodes N > 1`` the corpus is executed by the multi-node
 ``CampaignExecutor`` (real engine per node over batch shards);
 batch-keyed rng streams make the record set identical to ``--nodes 1``.
@@ -65,6 +69,12 @@ crashed worker's in-flight and queued batches re-issue to the live
 fleet — stateless batch keys keep the record set byte-identical to
 ``--nodes 1`` through any join/leave schedule.
 
+One process per chip: on a TPU host this process holds the chip once it
+has touched JAX, so ``--workers N`` and loopback ``--fabric-workers N``
+(children that each build an engine) fail fast with an actionable
+error (``repro.device.refuse_chip_children``). A standalone
+``--connect`` worker on its own machine still owns that machine's chip.
+
 Scenario lab (core/scenarios): ``--scenario NAME`` runs one named,
 fully declarative stress scenario (crash storms, wedged-straggler
 flaps, bursty arrivals, bimodal retuning, shared-store warm replay,
@@ -100,6 +110,10 @@ from repro.core.quality import QualityProbeConfig
 from repro.core.router import (AdaParseRouter, LinearStage, make_cls1_labels,
                                make_cls2_labels)
 from repro.data.synthetic import CorpusConfig, generate_corpus
+from repro.device import (ChipOwnedError, ensure_compile_cache,
+                          refuse_chip_children)
+
+ROUTER_CONFIGS = ("reduced", "published")
 
 
 def bleu_matrix(docs, ccfg, rng, parsers=P.REGRESSION_PARSERS):
@@ -142,15 +156,28 @@ def build_ft_router(train_docs, ccfg, rng) -> AdaParseRouter:
     return AdaParseRouter("ft", cls1, cls2)
 
 
-def build_llm_router(train_docs, ccfg, rng, *, sft_steps=150,
-                     dpo_steps=60, seed=0) -> AdaParseRouter:
-    from repro.common import unwrap
+def router_encoder_config(which: str = "reduced"):
+    """The ``adaparse-router`` encoder at its published widths or in
+    its reduced (CPU-sized) form."""
     from repro.configs import get_config
+
+    if which not in ROUTER_CONFIGS:
+        raise ValueError(f"router config {which!r} not in {ROUTER_CONFIGS}")
+    arch = get_config("adaparse-router")
+    return arch.model if which == "published" else arch.reduced().model
+
+
+def build_llm_router(train_docs, ccfg, rng, *, enc_cfg=None, sft_steps=150,
+                     dpo_steps=60, seed=0) -> AdaParseRouter:
+    """SFT + DPO + refit post-training of the CLS-III encoder
+    ``enc_cfg`` (default: the reduced router) from seeded weights."""
+    from repro.common import unwrap
     from repro.core import dpo as dpo_lib
     from repro.data.synthetic import preference_utility
     from repro.models import encoder as enc_lib
 
-    enc_cfg = get_config("adaparse-router").reduced().model
+    if enc_cfg is None:
+        enc_cfg = router_encoder_config()
     mat, _, cls1, toks, masks = fit_cls1_stage(train_docs, ccfg, rng,
                                                max_len=enc_cfg.max_len)
     reg = {"tokens": np.asarray(toks), "mask": np.asarray(masks),
@@ -240,6 +267,12 @@ def main(argv=None):
     ap.add_argument("--docs", type=int, default=600)
     ap.add_argument("--alpha", type=float, default=0.05)
     ap.add_argument("--variant", default="ft", choices=["ft", "llm"])
+    ap.add_argument("--router-config", default="reduced",
+                    choices=ROUTER_CONFIGS,
+                    help="CLS-III encoder of the llm variant: the reduced "
+                         "CPU-sized router (default) or the published "
+                         "adaparse-router widths (12 layers, d=768, 512 "
+                         "tokens)")
     ap.add_argument("--batch-size", type=int, default=256)
     ap.add_argument("--nodes", type=int, default=1)
     ap.add_argument("--workers", type=int, default=0,
@@ -338,6 +371,7 @@ def main(argv=None):
                          "flags conflict with this one")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    ensure_compile_cache()
 
     if args.connect is not None:
         # standalone fabric worker: everything about the campaign —
@@ -397,7 +431,10 @@ def main(argv=None):
             spec = get_scenario(args.scenario)
         except KeyError as e:
             ap.error(e.args[0])
-        res = run_scenario(spec, trace_dir=args.trace_dir)
+        try:
+            res = run_scenario(spec, trace_dir=args.trace_dir)
+        except ChipOwnedError as e:
+            ap.error(str(e))
         print(f"[serve] scenario {res.name} [{res.runtime}] "
               f"nodes={res.n_nodes} docs={res.n_docs} "
               f"records_match={res.records_match} "
@@ -450,6 +487,10 @@ def main(argv=None):
     if args.fabric_workers and args.nodes != 1:
         ap.error(f"--fabric-workers {args.fabric_workers} and --nodes "
                  f"{args.nodes} both set the fleet size; choose one")
+    if args.router_config != "reduced" and args.variant != "llm":
+        ap.error(f"--router-config {args.router_config} picks the CLS-III "
+                 f"encoder, which only the llm variant runs; add "
+                 f"--variant llm")
     if args.coordinator is not None and not args.fabric_workers:
         ap.error("--coordinator binds the fabric listener and waits "
                  "for standalone workers to dial in; it needs "
@@ -539,6 +580,13 @@ def main(argv=None):
                  f"{len(pools)} ({args.pools}); size the pools to the "
                  f"worker fleet")
 
+    if args.workers or (args.fabric_workers and args.coordinator is None):
+        try:
+            refuse_chip_children("process" if args.workers
+                                 else "loopback fabric")
+        except ChipOwnedError as e:
+            ap.error(str(e))
+
     if args.tuning_dir:
         # parent-side store handle: router training and single-node
         # runs consult (and, on kernel paths, populate) the same
@@ -552,7 +600,9 @@ def main(argv=None):
     train, test = docs[:n_train], docs[n_train:]
     rng = np.random.RandomState(args.seed + 1)
     router = (build_ft_router(train, ccfg, rng) if args.variant == "ft"
-              else build_llm_router(train, ccfg, rng))
+              else build_llm_router(
+                  train, ccfg, rng,
+                  enc_cfg=router_encoder_config(args.router_config)))
     nodes = (args.workers or args.fabric_workers
              or (len(pools) if pools else args.nodes))
     ecfg = EngineConfig(alpha=args.alpha, batch_size=args.batch_size,

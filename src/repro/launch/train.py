@@ -24,6 +24,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import checkpoint as ckpt_lib
+from repro.device import ensure_compile_cache
 from repro.distributed.fault import StragglerDetector
 from repro.distributed.meshrules import AxisRules, use_rules
 from repro.launch.mesh import make_mesh
@@ -43,6 +44,7 @@ def main(argv=None):
                     help="e.g. 16x16 (data x model) or 2x16x16")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    ensure_compile_cache()
 
     dims = tuple(int(x) for x in args.mesh.split("x"))
     axes = ("pod", "data", "model")[-len(dims):] if len(dims) == 3 \

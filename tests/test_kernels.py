@@ -484,3 +484,24 @@ def test_embedding_bag_sweep(r, d, b, bag, comb, dtype):
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32),
                                atol=TOL[dtype], rtol=TOL[dtype])
+
+
+# ---------------------------------------------------------------------------
+# f32math: the kernels' float32-accurate log / exp vs float64 numpy
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("fn,xs", [
+    ("log", np.arange(1, 300001, dtype=np.float64)),       # length feature
+    ("log", np.geomspace(1e-12, 1.0, 200001)),             # n-gram precisions
+    ("exp", np.linspace(-80.0, 1.0, 200001)),              # BLEU, brevity
+])
+def test_f32math_matches_float64(fn, xs):
+    from repro.kernels import f32math
+
+    x32 = xs.astype(np.float32)
+    got = np.asarray(jax.jit(getattr(f32math, fn))(jnp.asarray(x32)),
+                     np.float64)
+    want = getattr(np, fn)(x32.astype(np.float64))
+    rel = np.abs(got - want) / np.maximum(np.abs(want), 1e-30)
+    assert rel.max() < 3e-7          # a couple of float32 ulps

@@ -115,8 +115,11 @@ def test_heartbeat_reissue_never_duplicates_records(corpus, ft_router,
     run and the per-node doc counts sum to the corpus exactly."""
     ccfg, _ = corpus
     test, ecfg, single = single_run
+    # the deadline stretches by 0.3 s per task the last beat reported
+    # queued, so a muted worker's backlog (slowdown s per task) always
+    # outlasts it and a re-issue fires
     xcfg = ExecutorConfig(
-        n_nodes=2, runtime="process", heartbeat_timeout_s=0.5,
+        n_nodes=2, runtime="process", heartbeat_timeout_s=0.3,
         heartbeat_interval_s=0.1, straggler_grace_s=2.5,
         fault_injection=FaultInjection(mute_after=((1, 0),),
                                        mute_slowdown_s=slowdown))
@@ -317,9 +320,11 @@ def test_straggler_flap_recovers_without_overcommit(corpus, ft_router,
         n_nodes=2, runtime="process", prefetch_depth=2,
         heartbeat_timeout_s=0.5, heartbeat_interval_s=0.1,
         straggler_grace_s=2.5,
+        # the one-task mute window (3 s) outlasts the longest stretched
+        # heartbeat deadline, 0.5 s x (1 + 4 queued), so it must re-issue
         fault_injection=FaultInjection(mute_after=((1, 0),),
                                        unmute_after=((1, 2),),
-                                       mute_slowdown_s=0.9))
+                                       mute_slowdown_s=3.0))
     res = CampaignExecutor(ecfg, xcfg, ft_router, ccfg).run(test)
     _assert_same_records(single, res.records)
     assert res.reissued >= 1
