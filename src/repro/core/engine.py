@@ -60,7 +60,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 
 import numpy as np
 
@@ -169,7 +168,12 @@ class BatchTelemetry:
     feedback signal the adaptive campaign controller autotunes
     ``node_budget_weights`` from. Appended to the *ingest* engine's
     ``telemetry`` list (the engine that prepared/routed the batch);
-    ``complete_node`` records where the expensive re-parse ran."""
+    ``complete_node`` records where the expensive re-parse ran.
+
+    The ``*_s`` fields are cost-model node-seconds (parser throughput
+    models, ``router_cost_s``), not measurements: they drive the
+    simulated controller and the paper's tables. Measured stage times
+    are the ``core/obs`` spans."""
 
     batch_key: int | None
     n_docs: int
@@ -307,7 +311,8 @@ class AdaParseEngine:
                                prep.route_host["tokens"],
                                prep.route_host["mask"],
                                prep.route_host["valid_logit"])
-        idx = np.asarray(out["selected_idx"])
+        with obs.span("route.wait", prep.batch_key):
+            idx = np.asarray(out["selected_idx"])
         sel = np.sort(idx[idx >= 0]).astype(np.int64)
         k = len(prep.extracted)
         cheap = np.setdiff1d(np.arange(k), sel, assume_unique=False)
@@ -335,27 +340,35 @@ class AdaParseEngine:
         kernel on device backends (``EngineConfig.feature_kernel``).
         Pure w.r.t. engine state (no stats mutation), so it may run in
         a prefetch worker thread."""
-        rng = (stateless_rng(self.cfg.seed, batch_key)
-               if batch_key is not None else self.rng)
-        extracted = self.cheap_backend.parse_batch(
-            docs, self.ccfg, rng, image_degraded=self.image_degraded,
-            text_degraded=self.text_degraded)
-        max_len = (self.router.enc_cfg.max_len
-                   if self.router.variant == "llm" else None)
-        fast, tokens, mask = feat_lib.prepare_routing_inputs(
-            extracted, self.ccfg, max_len=max_len,
-            mode=self.cfg.feature_kernel)
-        fast = np.asarray(fast)          # CLS-I predict_proba is host-side
-        return PreparedBatch(docs, batch_key, rng, extracted, fast,
-                             self.cheap_backend.cost_batch(docs),
-                             self._route_host_features(docs, fast,
-                                                       tokens, mask))
+        with obs.span("prepare", batch_key):
+            rng = (stateless_rng(self.cfg.seed, batch_key)
+                   if batch_key is not None else self.rng)
+            with obs.span("prepare.channel", batch_key):
+                extracted = self.cheap_backend.parse_batch(
+                    docs, self.ccfg, rng,
+                    image_degraded=self.image_degraded,
+                    text_degraded=self.text_degraded)
+            max_len = (self.router.enc_cfg.max_len
+                       if self.router.variant == "llm" else None)
+            with obs.span("prepare.features", batch_key):
+                fast, tokens, mask = feat_lib.prepare_routing_inputs(
+                    extracted, self.ccfg, max_len=max_len,
+                    mode=self.cfg.feature_kernel)
+            # CLS-I predict_proba is host-side; on the kernel path this
+            # waits for the device to reach the features
+            with obs.span("prepare.wait", batch_key):
+                fast = np.asarray(fast)
+            return PreparedBatch(docs, batch_key, rng, extracted, fast,
+                                 self.cheap_backend.cost_batch(docs),
+                                 self._route_host_features(docs, fast,
+                                                           tokens, mask))
 
     def route_batch(self, prep: PreparedBatch) -> scheduler.BatchPlan:
         """CLS II/III + α-budget selection over a prepared batch."""
-        if self.router.variant == "llm" and self.cfg.device_route:
-            return self._device_plan(prep)
-        return self._host_plan(prep)
+        with obs.span("route", prep.batch_key):
+            if self.router.variant == "llm" and self.cfg.device_route:
+                return self._device_plan(prep)
+            return self._host_plan(prep)
 
     def complete_batch(self, prep: PreparedBatch, plan: scheduler.BatchPlan,
                        node_id: int = 0,
@@ -378,10 +391,12 @@ class AdaParseEngine:
             cost += self.expensive_backend.info.warm_start_s
             self._warmed_nodes.add(node_id)
         sel_docs = [prep.docs[i] for i in sel]
-        sel_pages = self.expensive_backend.parse_batch(
-            sel_docs, self.ccfg, prep.rng,
-            image_degraded=self.image_degraded,
-            text_degraded=self.text_degraded)
+        with obs.span("reparse", prep.batch_key,
+                      f"{len(sel)}/{k} docs expensive"):
+            sel_pages = self.expensive_backend.parse_batch(
+                sel_docs, self.ccfg, prep.rng,
+                image_degraded=self.image_degraded,
+                text_degraded=self.text_degraded)
         sel_cost = self.expensive_backend.cost_batch(sel_docs)
         cost += float(sel_cost.sum())
         records: list[ParseRecord] = []
@@ -401,7 +416,8 @@ class AdaParseEngine:
         probe_cost = 0.0
         if (self.probe is not None and prep.batch_key is not None
                 and self.probe.should_probe(prep.batch_key)):
-            quality = self.probe.score_records(prep.docs, records)
+            with obs.span("probe", prep.batch_key):
+                quality = self.probe.score_records(prep.docs, records)
             # probing is charged to the node that scored the batch
             # (this one), not treated as free measurement-plane work
             probe_cost = self.probe.cfg.cost_s_per_doc * k
@@ -411,32 +427,6 @@ class AdaParseEngine:
             complete_node=node_id, prepare_s=prep.ingest_cost_s,
             route_s=router_cost, complete_s=cost, probe_s=probe_cost,
             quality=quality))
-        # observability: per-stage latency histograms (always-on — a
-        # handful of dict ops per *batch*) and, when tracing is
-        # enabled, one span per stage reconstructed from the batch's
-        # already-measured durations (one record call each, so the hot
-        # path gains no extra timers)
-        reg = obs.metrics()
-        reg.observe("engine.prepare_s", prep.ingest_cost_s)
-        reg.observe("engine.route_s", router_cost)
-        reg.observe("engine.reparse_s", cost)
-        if probe_cost:
-            reg.observe("engine.probe_s", probe_cost)
-        rec = obs.recorder()
-        if rec.enabled:
-            key = prep.batch_key if prep.batch_key is not None else -1
-            t0 = time.time() - (prep.ingest_cost_s + router_cost + cost
-                                + probe_cost)
-            rec.span("prepare", key, t0, prep.ingest_cost_s,
-                     node=node_id)
-            t0 += prep.ingest_cost_s
-            rec.span("route", key, t0, router_cost, node=node_id)
-            t0 += router_cost
-            rec.span("reparse", key, t0, cost, node=node_id,
-                     detail=f"{len(sel)}/{k} docs expensive")
-            if probe_cost:
-                rec.span("probe", key, t0 + cost, probe_cost,
-                         node=node_id)
         return records
 
     # -- result cache ---------------------------------------------------------
@@ -456,16 +446,9 @@ class AdaParseEngine:
         key = self._cache_key(docs, batch_key) if use_cache else None
         cached = None
         if key is not None:
-            rec = obs.recorder()
-            if rec.enabled:
-                tw, tp = time.time(), time.perf_counter()
+            with obs.span("cache_lookup", batch_key) as lookup:
                 cached = self.cache.lookup(key)
-                dur = time.perf_counter() - tp
-                rec.span("cache_lookup", batch_key, tw, dur,
-                         cached=cached is not None)
-                obs.metrics().observe("engine.cache_lookup_s", dur)
-            else:
-                cached = self.cache.lookup(key)
+                lookup.cached = cached is not None
         if cached is not None:
             return key, None, cached
         return key, self.prepare_batch(docs, batch_key=batch_key), None
@@ -524,13 +507,24 @@ class AdaParseEngine:
         """Prefetch-overlapped campaign: the worker thread runs the host
         prepare (cheap channel + features, and cache lookups) for batch
         i+1..i+depth while the consumer routes/completes batch i. Batch
-        keys make the records identical to the sequential path."""
+        keys make the records identical to the sequential path. The
+        consumer's time blocked on the queue is the ``prefetch.wait``
+        span of the batch it got (the wait that meets the end of the
+        stream has no batch key)."""
 
         pf = Prefetcher(iter(batches), depth=self.cfg.prefetch_depth,
                         transform=lambda item: self.prepare_or_lookup(
                             item[1], batch_key=item[0]))
         try:
-            for key, prep, cached in pf:
+            while True:
+                with obs.span("prefetch.wait", "") as wait:
+                    item = next(pf, None)
+                    if item is not None:
+                        key, prep, cached = item
+                        wait.trace = key[1] if prep is None \
+                            else prep.batch_key
+                if item is None:
+                    return
                 if cached is not None:
                     self._account_cache_hit(cached, key[1])
                     yield cached

@@ -1,11 +1,22 @@
 """Fleet-wide observability plane: spans, metrics, exporters.
 
 Tracing is off by default and provably cheap when off: the module-level
-recorder starts as a :class:`NoopRecorder` whose ``span`` is a
-constant-time no-op, and instrumentation sites guard timestamp work
-behind ``recorder().enabled``. Metrics counters stay always-on — they
-are plain-int dict adds on control-plane paths only, never inside a
-per-document loop.
+recorder starts as a :class:`NoopRecorder`, :func:`span` then hands out
+one shared null context (an attribute read and a call: no clock read,
+no allocation, no jax import), and instrumentation sites guard any
+other timestamp work behind ``recorder().enabled``. Metrics counters
+stay always-on — they are plain-int dict adds on control-plane paths
+only, never inside a per-document loop.
+
+With the plane on, :func:`span` measures where the work happens: a
+``Span`` in the ring (start on ``time.time``, duration on
+``perf_counter``, its parent span and its thread) and the same interval
+as a ``jax.profiler.TraceAnnotation`` named ``adaparse.<name>``, so a
+profiler trace shows the program's stages on the host thread's line,
+on the device trace's clock. Turning the plane on also hooks Python's
+garbage collector (a ``gc`` span per generation-2 collection, counters
+per generation) and JAX's backend-compile event (a ``compile`` span,
+counter ``jax.compiles``); turning it off removes both hooks.
 
 Spans never cross the process boundary through new channels: each
 worker records into a bounded ring (``collections.deque`` with
@@ -23,9 +34,11 @@ re-binning error.
 """
 from __future__ import annotations
 
+import gc
 import json
 import math
 import os
+import threading
 import time
 from collections import deque
 from dataclasses import asdict, dataclass
@@ -36,11 +49,30 @@ from pathlib import Path
 #: canonical span names; anything else still records but gets no color.
 #: "complete" is the coordinator-emitted *winning* completion (exactly
 #: one per emitted batch — the span-conservation invariant); "reparse"
-#: is the engine's expensive-stage timing, of which losing re-issue
-#: attempts may emit extras.
-SPAN_STAGES = ("prepare", "route", "complete", "reparse", "probe",
-               "cache_lookup", "forward", "reissue", "dedup", "round",
+#: is the engine's measured expensive parse, of which losing re-issue
+#: attempts may emit extras. The dotted names are children measured
+#: inside their stage (``prepare.wait``: the prefetch thread blocked on
+#: the device for the features; ``route.wait``: the consumer blocked on
+#: the route step); ``prefetch.wait`` is the consumer blocked on the
+#: prefetch queue; ``gc`` and ``compile`` are pauses the plane's hooks
+#: record.
+SPAN_STAGES = ("prepare", "prepare.channel", "prepare.features",
+               "prepare.wait", "route", "route.wait", "complete",
+               "reparse", "probe", "cache_lookup", "prefetch.wait", "gc",
+               "compile", "forward", "reissue", "dedup", "round",
                "scenario", "join", "leave", "admission_rejected")
+
+#: the latency histogram each measured engine stage feeds when its span
+#: closes (``--metrics-out``); only while the plane is on
+STAGE_HISTOGRAMS = {"prepare": "engine.prepare_s", "route": "engine.route_s",
+                    "reparse": "engine.reparse_s", "probe": "engine.probe_s",
+                    "cache_lookup": "engine.cache_lookup_s"}
+
+#: the profiler-trace name of a span is this prefix and its name
+ANNOTATION_PREFIX = "adaparse."
+
+#: JAX's monitoring event for one backend compile (its duration)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 #: chrome://tracing reserved color names per stage
 _CNAME = {
@@ -62,12 +94,13 @@ _CNAME = {
     "join": "cq_build_attempt_passed",
     "leave": "cq_build_attempt_failed",
     "admission_rejected": "cq_build_failed",
+    # waits and pauses
+    "prepare.wait": "thread_state_unknown",
+    "route.wait": "thread_state_unknown",
+    "prefetch.wait": "thread_state_sleeping",
+    "gc": "terrible",
+    "compile": "bad",
 }
-
-#: chrome trace thread ids must be non-negative; the coordinator
-#: (node -1) gets its own high lane
-_COORD_TID = 999
-
 
 @dataclass
 class Span:
@@ -83,6 +116,8 @@ class Span:
     cached: bool = False
     abandoned: bool = False
     detail: str = ""
+    parent: str = ""    # the enclosing span's name on the same thread
+    thread: str = ""    # the recording thread's name
 
     def to_dict(self) -> dict:
         return asdict(self)
@@ -90,6 +125,36 @@ class Span:
     @classmethod
     def from_dict(cls, d: dict) -> "Span":
         return cls(**d)
+
+
+class _NullSpan:
+    """What :func:`span` hands out with the plane off: one shared
+    instance that enters, exits and takes field updates doing nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def __setattr__(self, name, value):
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+#: the open measured spans of each thread, innermost last: (name, trace)
+_open = threading.local()
+
+
+def _stack() -> list:
+    try:
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
 
 
 class NoopRecorder:
@@ -121,21 +186,32 @@ class RingRecorder:
     enabled = True
 
     def __init__(self, cap: int = 8192, node: int = -1):
+        from jax.profiler import TraceAnnotation
+
         self.cap = int(cap)
         self.node = int(node)
         self.pid = os.getpid()
         self._ring: deque = deque(maxlen=self.cap)
         self.recorded = 0
         self.shipped = 0
+        self.annotation = TraceAnnotation
+        self._gc_open = None
 
     def span(self, name, trace, start, dur, node=None, attempt=0,
-             cached=False, abandoned=False, detail=""):
+             cached=False, abandoned=False, detail="", parent=None):
+        """Record one span whose times the caller took (instants and
+        the coordinator's lifecycle spans); ``parent`` defaults to the
+        innermost measured span open on this thread."""
+        if parent is None:
+            stack = _stack()
+            parent = stack[-1][0] if stack else ""
         self._ring.append(Span(
             name=name, trace=str(trace),
             node=self.node if node is None else int(node),
             pid=self.pid, start=float(start), dur=float(dur),
             attempt=int(attempt), cached=bool(cached),
-            abandoned=bool(abandoned), detail=detail))
+            abandoned=bool(abandoned), detail=detail, parent=parent,
+            thread=threading.current_thread().name))
         self.recorded += 1
 
     def drain(self, limit=None):
@@ -155,6 +231,81 @@ class RingRecorder:
     @property
     def dropped(self) -> int:
         return max(0, self.recorded - self.shipped - len(self._ring))
+
+    def on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: count every collection by generation,
+        and time each generation-2 one as a ``gc`` span (detail: the
+        objects it collected) on the thread that paid for it."""
+        gen = info["generation"]
+        if phase == "start":
+            if gen == 2:
+                self._gc_open = _Measured(self, "gc", None).__enter__()
+            return
+        _registry.count(f"gc.collections.gen{gen}")
+        if gen == 2 and self._gc_open is not None:
+            sp, self._gc_open = self._gc_open, None
+            sp.detail = f"{info['collected']} collected"
+            sp.__exit__(None, None, None)
+
+    def on_duration(self, event: str, duration: float, **kw) -> None:
+        """JAX monitoring hook: one ``compile`` span per backend compile
+        (detail: the jitted function), counted as ``jax.compiles``."""
+        if event != COMPILE_EVENT:
+            return
+        _registry.count("jax.compiles")
+        stack = _stack()
+        self.span("compile", stack[-1][1] if stack else "",
+                  time.time() - duration, duration,
+                  detail=str(kw.get("fun_name", "")))
+
+
+class _Measured:
+    """One span measured on both clocks while it is open (plane on)."""
+
+    __slots__ = ("rec", "name", "trace", "detail", "cached", "parent",
+                 "t_wall", "t0", "ann")
+
+    def __init__(self, rec: RingRecorder, name: str, trace, detail=""):
+        self.rec, self.name, self.trace = rec, name, trace
+        self.detail, self.cached = detail, False
+
+    def __enter__(self):
+        stack = _stack()
+        if self.trace is None and stack:    # inherit the enclosing id
+            self.trace = stack[-1][1]
+        self.parent = stack[-1][0] if stack else ""
+        stack.append((self.name, self.trace))
+        self.ann = self.rec.annotation(ANNOTATION_PREFIX + self.name)
+        self.ann.__enter__()
+        self.t_wall = time.time()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter() - self.t0
+        self.ann.__exit__(None, None, None)
+        _stack().pop()
+        self.rec.span(self.name, "" if self.trace is None else self.trace,
+                      self.t_wall, dur, cached=self.cached,
+                      detail=self.detail, parent=self.parent)
+        hist = STAGE_HISTOGRAMS.get(self.name)
+        if hist is not None:
+            _registry.observe(hist, dur)
+        return False
+
+
+def span(name: str, trace, detail: str = ""):
+    """Context manager timing the enclosed work as span ``name`` of
+    trace ``trace`` (the batch key). Off: the shared null context. On:
+    a ``Span`` in the ring and an ``adaparse.<name>`` profiler
+    annotation over the same interval; the stage spans of
+    :data:`STAGE_HISTOGRAMS` also feed their histogram. Fields known
+    only inside the block (``cached``, ``trace``, ``detail``) may be
+    set on the object ``with`` binds; the null context ignores them."""
+    rec = _recorder
+    if not rec.enabled:
+        return _NULL_SPAN
+    return _Measured(rec, name, trace, detail)
 
 
 # -------------------------------------------------------------- metrics
@@ -322,8 +473,11 @@ class TraceWriter:
       of truth for span-conservation checks), plus a trailing
       ``{"meta": ...}`` line with drop counts;
     - ``trace.json``: Chrome ``trace_event`` JSON — one lane per
-      worker (tid = node id, coordinator on its own lane),
-      stage-colored, loadable in chrome://tracing or Perfetto.
+      (worker, thread) (the coordinator's lanes last; a main thread's
+      lane is named after its worker alone), stage-colored, loadable
+      in chrome://tracing or Perfetto. A prefetch thread's ``prepare``
+      overlaps the consumer's ``route`` in time, so each thread needs
+      its own lane.
     """
 
     def __init__(self, trace_dir):
@@ -341,18 +495,22 @@ class TraceWriter:
                                          "dropped": dropped}}) + "\n")
         events = [{"name": "process_name", "ph": "M", "pid": 0, "tid": 0,
                    "args": {"name": "adaparse campaign"}}]
-        for node in sorted({s.node for s in spans}):
-            tid = _COORD_TID if node < 0 else node
+        lanes = sorted({(s.node < 0, s.node, s.thread) for s in spans})
+        tids = {lane[1:]: tid for tid, lane in enumerate(lanes)}
+        for (node, thread), tid in tids.items():
             label = "coordinator" if node < 0 else f"worker {node}"
+            if thread not in ("", "MainThread"):
+                label = f"{label} {thread}"
             events.append({"name": "thread_name", "ph": "M", "pid": 0,
                            "tid": tid, "args": {"name": label}})
         for s in spans:
             ev = {"name": s.name, "cat": s.name, "pid": 0,
-                  "tid": _COORD_TID if s.node < 0 else s.node,
+                  "tid": tids[s.node, s.thread],
                   "ts": s.start * 1e6,
                   "args": {"trace": s.trace, "attempt": s.attempt,
                            "cached": s.cached, "abandoned": s.abandoned,
-                           "detail": s.detail, "pid": s.pid}}
+                           "detail": s.detail, "pid": s.pid,
+                           "parent": s.parent}}
             if s.dur > 0:
                 ev["ph"] = "X"
                 ev["dur"] = s.dur * 1e6
@@ -410,10 +568,24 @@ def metrics() -> Registry:
 def configure(enabled: bool = False, cap: int = 8192, node: int = -1):
     """(Re)install this process's recorder. Called once per worker
     process at startup and once per run by the coordinator; installing
-    a fresh ring discards spans from any earlier run in this process."""
+    a fresh ring discards spans from any earlier run in this process.
+    An enabled recorder hooks the garbage collector and JAX's compile
+    event until the next call replaces it."""
     global _recorder
+    old = _recorder
+    if old.enabled:
+        import jax.monitoring
+
+        gc.callbacks.remove(old.on_gc)
+        jax.monitoring.unregister_event_duration_listener(old.on_duration)
     _recorder = RingRecorder(cap=cap, node=node) if enabled \
         else NoopRecorder()
+    if enabled:
+        import jax.monitoring
+
+        gc.callbacks.append(_recorder.on_gc)
+        jax.monitoring.register_event_duration_secs_listener(
+            _recorder.on_duration)
     return _recorder
 
 

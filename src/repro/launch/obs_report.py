@@ -6,11 +6,15 @@ Replays ``spans.jsonl`` (the span log ``core/obs.TraceWriter`` wrote)
 and prints:
 
 - a per-stage latency table — count, p50/p95/p99 (exact percentiles
-  over the recorded durations, not histogram-bucket estimates), and
-  total busy seconds per span stage;
-- a per-worker table — span count, busy seconds (work-stage spans
-  only: ``prepare``/``route``/``reparse``/``probe``/``cache_lookup``),
-  busy fraction of the trace window, and the stages seen on that lane;
+  over the recorded durations, not histogram-bucket estimates), total
+  seconds and total self seconds per span stage (a span's self time is
+  its duration minus what its child spans on the same thread cover);
+  the waits and pauses (``prepare.wait``, ``route.wait``,
+  ``prefetch.wait``, ``gc``, ``compile``) in a table of their own;
+- a per-worker table — span count, busy seconds (the self time of the
+  work stages, so a stage's device wait or a collection inside it is
+  not counted as work), busy fraction of the trace window, seconds
+  waited or paused, and the stages seen on that lane;
 - the re-issue cause breakdown (``crash`` / ``wedged`` / ``stalled``,
   parsed from the coordinator's ``reissue`` span details) and the
   dedup / cache-hit counts the span-conservation laws guarantee;
@@ -34,10 +38,14 @@ from collections import Counter, defaultdict
 
 from repro.core import obs
 
-#: Stages whose duration is real work on a worker lane. ``complete``
+#: Stages whose self time is real work on a worker lane. ``complete``
 #: is excluded: the coordinator attributes it to the winning worker
 #: with the full batch wall, which already contains the stage spans.
-WORK_STAGES = ("prepare", "route", "reparse", "probe", "cache_lookup")
+WORK_STAGES = ("prepare", "prepare.channel", "prepare.features", "route",
+               "reparse", "probe", "cache_lookup")
+#: Spans that are a thread blocked or paused, never work
+WAIT_STAGES = ("prepare.wait", "route.wait", "prefetch.wait", "gc",
+               "compile")
 
 
 def _pct(vals: list, q: float) -> float:
@@ -52,6 +60,33 @@ def _lane(node: int) -> str:
     return "coordinator" if node < 0 else f"worker {node}"
 
 
+def self_times(spans) -> list:
+    """Each span's duration minus the durations of its children: the
+    spans recorded on the same thread of the same process, inside it,
+    that name it as their parent. Spans without a thread (logs written
+    before spans had one) keep their whole duration."""
+    out = [s.dur for s in spans]
+    lanes: dict[tuple, list] = defaultdict(list)
+    for i, s in enumerate(spans):
+        if s.thread and s.dur > 0:
+            lanes[s.pid, s.thread].append(i)
+    for idx in lanes.values():
+        idx.sort(key=lambda i: (spans[i].start, -spans[i].dur))
+        open_: list = []                 # enclosing spans, innermost last
+        for i in idx:
+            s = spans[i]
+            while open_ and (spans[open_[-1]].start + spans[open_[-1]].dur
+                             <= s.start):
+                open_.pop()
+            for j in reversed(open_):
+                if (spans[j].name == s.parent
+                        and spans[j].start + spans[j].dur > s.start):
+                    out[j] -= s.dur
+                    break
+            open_.append(i)
+    return out
+
+
 def summarize(spans, meta: dict | None = None) -> dict:
     """The report as a plain dict (the CLI renders it; tests assert
     on it)."""
@@ -61,18 +96,23 @@ def summarize(spans, meta: dict | None = None) -> dict:
     window = (max(ends) - min(starts)) if spans else 0.0
 
     by_stage: dict[str, list] = defaultdict(list)
+    self_s: Counter = Counter()
     by_worker: dict[int, dict] = defaultdict(
-        lambda: {"spans": 0, "busy_s": 0.0, "stages": Counter()})
+        lambda: {"spans": 0, "busy_s": 0.0, "wait_s": 0.0,
+                 "stages": Counter()})
     causes: Counter = Counter()
     n_complete = n_dedup = n_cached = 0
     fabric: Counter = Counter()
-    for s in spans:
+    for s, own in zip(spans, self_times(spans)):
         by_stage[s.name].append(s.dur)
+        self_s[s.name] += own
         w = by_worker[s.node]
         w["spans"] += 1
         w["stages"][s.name] += 1
         if s.name in WORK_STAGES:
-            w["busy_s"] += s.dur
+            w["busy_s"] += own
+        elif s.name in WAIT_STAGES:
+            w["wait_s"] += own
         if s.name == "reissue":
             causes[s.detail.split(" ", 1)[0] or "unknown"] += 1
         elif s.name == "complete":
@@ -86,12 +126,12 @@ def summarize(spans, meta: dict | None = None) -> dict:
     stages = {
         name: {"n": len(durs), "p50_s": _pct(durs, 0.50),
                "p95_s": _pct(durs, 0.95), "p99_s": _pct(durs, 0.99),
-               "total_s": sum(durs)}
+               "total_s": sum(durs), "self_s": self_s[name]}
         for name, durs in by_stage.items()}
     workers = {
         node: {"spans": w["spans"], "busy_s": w["busy_s"],
                "busy_frac": (w["busy_s"] / window) if window else 0.0,
-               "stages": dict(w["stages"])}
+               "wait_s": w["wait_s"], "stages": dict(w["stages"])}
         for node, w in by_worker.items()}
     return {"n_spans": len(spans), "dropped": meta.get("dropped", 0),
             "window_s": window, "stages": stages, "workers": workers,
@@ -104,22 +144,31 @@ def summarize(spans, meta: dict | None = None) -> dict:
 def render(rep: dict) -> str:
     out = [f"[obs] {rep['n_spans']} spans over {rep['window_s']:.2f} s "
            f"({rep['dropped']} dropped at the ring)"]
-    out.append(f"{'stage':<14}{'n':>6}{'p50 ms':>10}{'p95 ms':>10}"
-               f"{'p99 ms':>10}{'total s':>10}")
     order = {n: i for i, n in enumerate(obs.SPAN_STAGES)}
-    for name in sorted(rep["stages"], key=lambda n: order.get(n, 99)):
-        st = rep["stages"][name]
-        out.append(f"{name:<14}{st['n']:>6}{st['p50_s'] * 1e3:>10.2f}"
-                   f"{st['p95_s'] * 1e3:>10.2f}{st['p99_s'] * 1e3:>10.2f}"
-                   f"{st['total_s']:>10.2f}")
-    out.append("")
+    names = sorted(rep["stages"], key=lambda n: order.get(n, 99))
+    for title, rows in (("stage", [n for n in names
+                                   if n not in WAIT_STAGES]),
+                        ("wait or pause", [n for n in names
+                                           if n in WAIT_STAGES])):
+        if not rows:
+            continue
+        out.append(f"{title:<18}{'n':>6}{'p50 ms':>10}{'p95 ms':>10}"
+                   f"{'p99 ms':>10}{'total s':>10}{'self s':>10}")
+        for name in rows:
+            st = rep["stages"][name]
+            out.append(f"{name:<18}{st['n']:>6}{st['p50_s'] * 1e3:>10.2f}"
+                       f"{st['p95_s'] * 1e3:>10.2f}"
+                       f"{st['p99_s'] * 1e3:>10.2f}"
+                       f"{st['total_s']:>10.2f}{st['self_s']:>10.2f}")
+        out.append("")
     out.append(f"{'lane':<14}{'spans':>6}{'busy s':>10}{'busy %':>8}"
-               f"  stages")
+               f"{'waited s':>10}  stages")
     for node in sorted(rep["workers"]):
         w = rep["workers"][node]
         seen = ",".join(sorted(w["stages"]))
         out.append(f"{_lane(node):<14}{w['spans']:>6}{w['busy_s']:>10.2f}"
-                   f"{w['busy_frac'] * 100:>7.1f}%  {seen}")
+                   f"{w['busy_frac'] * 100:>7.1f}%{w['wait_s']:>10.2f}"
+                   f"  {seen}")
     out.append("")
     causes = rep["reissue_causes"]
     cause_s = (", ".join(f"{c} {n}" for c, n in sorted(causes.items()))

@@ -85,13 +85,17 @@ registry. The fleet shape and fault schedule live in the spec, so
 campaign-shape flags conflict with ``--scenario``.
 
 Observability (core/obs): ``--trace-dir DIR`` turns the tracing plane
-on and writes the run's span log (``spans.jsonl``), a Chrome
-``trace_event`` timeline (``trace.json``, one lane per worker,
-stage-colored), and folded metrics; ``--metrics-out FILE`` exports the
-fleet-folded counters/gauges/latency-histograms as Prometheus text;
-``--status-interval S`` prints a live one-line fleet status to stderr
-while a worker fleet drains. All three default off, and with them off
-the recorder is a noop — the hot path pays nothing.
+on and writes the run's span log (``spans.jsonl``, each stage measured
+where it runs), a Chrome ``trace_event`` timeline (``trace.json``, one
+lane per worker thread, stage-colored), and folded metrics;
+``--metrics-out FILE`` exports the fleet-folded counters/gauges/latency
+histograms as Prometheus text, and turns the plane on too: the
+engine's stage histograms (``engine.prepare_s`` and the rest) are
+observed from the measured spans as they close, so only while the plane
+is on. ``--status-interval S`` prints a live one-line fleet status to
+stderr while a worker fleet drains. All three default off, and with
+them off the recorder is a noop — the hot path pays a null context per
+stage.
 """
 from __future__ import annotations
 
@@ -353,9 +357,10 @@ def main(argv=None):
                          "summarize with repro.launch.obs_report. "
                          "Composes with --scenario")
     ap.add_argument("--metrics-out", default=None, metavar="FILE",
-                    help="write the fleet-folded metrics registry "
-                         "(counters, gauges, log2-bucket latency "
-                         "histograms) as Prometheus text to FILE")
+                    help="turn the observability plane on and write "
+                         "the fleet-folded metrics registry (counters, "
+                         "gauges, log2-bucket latency histograms of the "
+                         "measured stages) as Prometheus text to FILE")
     ap.add_argument("--status-interval", type=float, default=0.0,
                     metavar="S",
                     help="print a live one-line status to stderr every "

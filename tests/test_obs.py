@@ -1,10 +1,13 @@
 """The observability plane (core/obs): recorder noop contract, exact
 log2-histogram folding, Prometheus text rendering, trace-writer
-round-trips — and the span-conservation property on a real 4-worker
-crash/flap process campaign: every emitted batch has exactly one
-winning ``complete`` span, every dropped duplicate a ``dedup`` span,
-every re-issue a ``reissue`` span, and the trace-file replay counts
-match the ``ExecutorResult`` counters exactly."""
+round-trips, the engine's measured stage spans and the plane's
+garbage-collector and compile hooks — and the span-conservation
+property on a real 4-worker crash/flap process campaign: every emitted
+batch has exactly one winning ``complete`` span, every dropped
+duplicate a ``dedup`` span, every re-issue a ``reissue`` span, and the
+trace-file replay counts match the ``ExecutorResult`` counters
+exactly."""
+import gc
 import json
 from collections import Counter
 
@@ -138,20 +141,37 @@ def _some_spans():
 
 
 def test_trace_writer_roundtrip_and_chrome_json(tmp_path):
-    spans = _some_spans()
+    # a prefetch thread's prepare overlaps the main thread's route on
+    # worker 0: each thread gets a lane of its own
+    spans = _some_spans() + [
+        obs.Span("route", "8", 0, 4242, 100.2, 0.4, parent="",
+                 thread="MainThread"),
+        obs.Span("prepare", "8", 0, 4242, 100.1, 0.6, thread="prefetch")]
     chrome = obs.TraceWriter(tmp_path).write(spans, dropped=2)
     got, meta = obs.load_spans(tmp_path)
     assert got == spans                  # lossless jsonl round-trip
-    assert meta == {"n_spans": 3, "dropped": 2}
+    assert meta == {"n_spans": 5, "dropped": 2}
     doc = json.loads(chrome.read_text())
     events = doc["traceEvents"]
-    lanes = {e["args"]["name"] for e in events
+    lanes = {e["tid"]: e["args"]["name"] for e in events
              if e.get("name") == "thread_name"}
-    assert {"worker 0", "worker 1", "worker 2"} <= lanes
+    assert set(lanes.values()) == {"worker 0", "worker 1", "worker 2",
+                                   "worker 0 prefetch"}
+    by_name = {(e["name"], e["args"]["trace"]): lanes[e["tid"]]
+               for e in events if e.get("ph") in ("X", "i")}
+    assert by_name["route", "8"] == "worker 0"
+    assert by_name["prepare", "8"] == "worker 0 prefetch"
     durs = [e for e in events if e.get("ph") == "X"]
     instants = [e for e in events if e.get("ph") == "i"]
-    assert len(durs) == 2 and len(instants) == 1
+    assert len(durs) == 4 and len(instants) == 1
     assert all(e["ts"] >= 100.0 * 1e6 for e in durs)
+    # span logs written before spans had a parent and a thread load
+    old = tmp_path / "old"
+    old.mkdir()
+    (old / "spans.jsonl").write_text(json.dumps(
+        {k: v for k, v in spans[0].to_dict().items()
+         if k not in ("parent", "thread")}) + "\n")
+    assert obs.load_spans(old)[0] == [spans[0]]
 
 
 def test_obs_report_summarizes_stages_workers_and_causes(tmp_path):
@@ -163,17 +183,166 @@ def test_obs_report_summarizes_stages_workers_and_causes(tmp_path):
         obs.Span("reissue", "9", 1, 4242, 102.5, 0.0,
                  detail="wedged worker 0, complete stage"),
     ]
+    # one measured batch on worker 0: the prefetch thread prepares
+    # (a collection inside its cheap channel, then a device wait), the
+    # main thread waits on the queue, then routes and waits on the step
+    pf, main = dict(thread="prefetch"), dict(thread="MainThread")
+    spans += [
+        obs.Span("prepare", "10", 0, 4242, 103.0, 0.30, **pf),
+        obs.Span("prepare.channel", "10", 0, 4242, 103.0, 0.05,
+                 parent="prepare", **pf),
+        obs.Span("gc", "10", 0, 4242, 103.01, 0.02,
+                 parent="prepare.channel", detail="7 collected", **pf),
+        obs.Span("prepare.wait", "10", 0, 4242, 103.1, 0.20,
+                 parent="prepare", **pf),
+        obs.Span("prefetch.wait", "10", 0, 4242, 103.0, 0.31, **main),
+        obs.Span("route", "10", 0, 4242, 103.31, 0.25, **main),
+        obs.Span("route.wait", "10", 0, 4242, 103.32, 0.20,
+                 parent="route", **main),
+    ]
     obs.TraceWriter(tmp_path).write(spans)
     rep = obs_report.main(["--trace-dir", str(tmp_path)])
-    assert rep["n_spans"] == 5
-    assert rep["stages"]["prepare"]["n"] == 1
-    assert rep["stages"]["prepare"]["p50_s"] == pytest.approx(0.5)
+    assert rep["n_spans"] == 12
+    assert rep["stages"]["prepare"]["n"] == 2
+    assert rep["stages"]["prepare"]["p50_s"] == pytest.approx(0.3)
     assert rep["reissue_causes"] == {"crash": 1, "wedged": 1}
     assert rep["complete"] == 1 and rep["complete_cached"] == 1
     assert rep["dedup"] == 1
-    assert 0 in rep["workers"] and rep["workers"][0]["busy_s"] > 0
+    # self time: a span less its children on its own thread (the old
+    # threadless prepare keeps its whole 0.5 s)
+    own = {k: v["self_s"] for k, v in rep["stages"].items()}
+    assert own["prepare"] == pytest.approx(0.5 + 0.30 - 0.05 - 0.20)
+    assert own["prepare.channel"] == pytest.approx(0.03)
+    assert own["route"] == pytest.approx(0.05)
+    assert own["prefetch.wait"] == pytest.approx(0.31)
+    # work is self time of the work stages; waits and pauses are apart
+    w0 = rep["workers"][0]
+    assert w0["busy_s"] == pytest.approx(0.55 + 0.03 + 0.05)
+    assert w0["wait_s"] == pytest.approx(0.20 + 0.02 + 0.31 + 0.20)
     text = obs_report.render(rep)
     assert "crash 1" in text and "wedged 1" in text
+    waits = text[text.index("wait or pause"):text.index("lane")]
+    assert all(n in waits for n in ("prefetch.wait", "gc", "route.wait"))
+    assert "reparse" not in waits and "\nroute " not in waits
+
+
+# ---------------------------------------------------------------------------
+# Measured spans inside the engine, and the plane's hooks
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def tiny_llm(corpus):
+    """A one-layer float32 llm router over the shared corpus's vocab,
+    routed on the device path with the fast_features kernel forced
+    (interpret mode here), and 3 batches of 16 documents."""
+    from repro.common import unwrap
+    from repro.configs.base import EncoderConfig
+    from repro.core.router import AdaParseRouter, LinearStage
+    from repro.models import encoder as enc_lib
+
+    ccfg, docs = corpus
+    cfg = EncoderConfig(name="t", n_layers=1, d_model=16, n_heads=2,
+                        d_ff=32, vocab_size=ccfg.vocab_size, max_len=12,
+                        param_dtype="float32", compute_dtype="float32")
+    router = AdaParseRouter("llm", LinearStage(np.zeros(8), 1.0), None,
+                            enc_cfg=cfg,
+                            enc_params=unwrap(enc_lib.init_encoder(cfg, 0)))
+    ecfg = EngineConfig(alpha=0.1, batch_size=16, prefetch_depth=1,
+                        feature_kernel="force")
+    return ecfg, router, ccfg, docs[:48]
+
+
+def _run_tiny(tiny_llm, enabled: bool):
+    from repro.core.engine import AdaParseEngine
+
+    ecfg, router, ccfg, docs = tiny_llm
+    rec = obs.configure(enabled)
+    base = obs.metrics().snapshot()
+    try:
+        out = AdaParseEngine(ecfg, router, ccfg).run(docs)
+        spans = rec.drain()
+    finally:
+        obs.configure(False)
+    assert len(out) == len(docs)
+    return spans, obs.diff(obs.metrics().snapshot(), base)
+
+
+def test_engine_spans_are_measured_where_the_work_happens(tiny_llm):
+    spans, metrics = _run_tiny(tiny_llm, True)
+    assert {s.name for s in spans} <= set(obs.SPAN_STAGES)
+    stages = [s for s in spans if s.name not in ("gc", "compile")]
+    by = Counter((s.trace, s.name) for s in stages)
+    keys = ["0", "1", "2"]
+    children = {"prepare": ("prepare.channel", "prepare.features",
+                            "prepare.wait"),
+                "route": ("route.wait",)}
+    for k in keys:
+        for parent, kids in children.items():
+            assert by[k, parent] == 1
+            (p,) = [s for s in stages if (s.trace, s.name) == (k, parent)]
+            for kid in kids:
+                assert by[k, kid] == 1
+                (c,) = [s for s in stages if (s.trace, s.name) == (k, kid)]
+                assert (c.parent, c.thread) == (parent, p.thread)
+                # the child lies inside its parent (two clocks: start on
+                # time.time, duration on perf_counter)
+                assert c.start >= p.start - 1e-4
+                assert c.start + c.dur <= p.start + p.dur + 1e-4
+        assert by[k, "reparse"] == 1 and by[k, "prefetch.wait"] == 1
+    # one wait per batch taken from the queue, and the wait that met
+    # the end of the stream
+    assert sum(s.name == "prefetch.wait" for s in stages) == len(keys) + 1
+    assert all(s.dur >= 0 for s in spans)
+    # the prefetch thread prepares; the consumer waits, routes, reparses
+    threads = {s.name: s.thread for s in stages}
+    assert threads["prepare"] != threads["route"] == threads["reparse"] \
+        == threads["prefetch.wait"]
+    hists = metrics["hists"]
+    for name in ("engine.prepare_s", "engine.route_s", "engine.reparse_s"):
+        assert hists[name]["total"] == len(keys)
+    assert hists["engine.route_s"]["sum"] == pytest.approx(
+        sum(s.dur for s in stages if s.name == "route"))
+
+
+def test_engine_off_plane_records_and_observes_nothing(tiny_llm):
+    spans, metrics = _run_tiny(tiny_llm, False)
+    assert spans == []
+    assert not any(k.startswith("engine.") for k in metrics["hists"])
+    assert obs.span("prepare", 0) is obs.span("route", 1, "x")
+    with obs.span("cache_lookup", 2) as lookup:
+        lookup.cached = True             # ignored, never raises
+
+
+def test_plane_hooks_gc_and_compiles_until_turned_off():
+    import jax
+    import jax.numpy as jnp
+
+    rec = obs.configure(True)
+    base = obs.metrics().snapshot()
+    try:
+        with obs.span("route", 5):
+            gc.collect()
+        collected = rec.drain()
+        with obs.span("route", 6):
+            jax.jit(lambda x: x * 3 + 1)(jnp.arange(7.0))
+        compiled = rec.drain()
+    finally:
+        obs.configure(False)
+    counters = obs.diff(obs.metrics().snapshot(), base)["counters"]
+    (g,) = [s for s in collected if s.name == "gc"]
+    assert g.detail.endswith(" collected") and g.dur > 0
+    assert (g.parent, g.trace) == ("route", "5")
+    assert counters["gc.collections.gen2"] >= 1
+    compiles = [s for s in compiled if s.name == "compile"]
+    assert {(s.parent, s.trace) for s in compiles} == {("route", "6")}
+    assert "jit(<lambda>)" in {s.detail for s in compiles}
+    assert counters["jax.compiles"] == len(compiles)
+    assert rec.on_gc not in gc.callbacks
+    # the hooks are gone: a later collection or compile records nothing
+    gc.collect()
+    jax.jit(lambda x: x * 5 + 1)(jnp.arange(7.0))
+    assert rec.drain() == [] and not obs.recorder().enabled
 
 
 # ---------------------------------------------------------------------------
