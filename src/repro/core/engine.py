@@ -303,14 +303,17 @@ class AdaParseEngine:
         import jax
 
         if self._route_step is None:
-            self._route_step = jax.jit(make_route_step(
-                self.router.enc_cfg, self.cfg.alpha,
-                cheap_idx=self.router.cheap_idx,
-                expensive_idx=self.router.expensive_idx))
+            step = make_route_step(self.router.enc_cfg, self.cfg.alpha,
+                                   cheap_idx=self.router.cheap_idx,
+                                   expensive_idx=self.router.expensive_idx)
+            self._route_step = jax.jit(step)
+            self._route_attention_kernel = step.attention_kernel
         out = self._route_step(self.router.enc_params,
                                prep.route_host["tokens"],
                                prep.route_host["mask"],
                                prep.route_host["valid_logit"])
+        if self._route_attention_kernel:
+            obs.metrics().count(obs.ROUTE_ATTENTION_KERNEL)
         with obs.span("route.wait", prep.batch_key):
             idx = np.asarray(out["selected_idx"])
         sel = np.sort(idx[idx >= 0]).astype(np.int64)

@@ -68,6 +68,10 @@ STAGE_HISTOGRAMS = {"prepare": "engine.prepare_s", "route": "engine.route_s",
                     "reparse": "engine.reparse_s", "probe": "engine.probe_s",
                     "cache_lookup": "engine.cache_lookup_s"}
 
+#: counter: batches whose route step ran the encoder attention kernel
+#: (``engine.route_batch``, device path), so a run shows it engaged
+ROUTE_ATTENTION_KERNEL = "route.attention_kernel"
+
 #: the profiler-trace name of a span is this prefix and its name
 ANNOTATION_PREFIX = "adaparse."
 

@@ -131,15 +131,25 @@ def make_route_step(enc_cfg: EncoderConfig, alpha: float,
     selection machinery as a single XLA computation, and the production
     selection path of the LLM-variant engine (engine.py).
 
+    Forward-only, so on TPU backends, or under ``force_kernel`` (which
+    forces ``budget_route``'s kernel too), the encoder's attention block
+    is the ``kernels.encoder_attention`` kernel; elsewhere it is the
+    einsum block the training losses use.
+
     ``selected_idx`` is (⌊α·B⌋,) int32 source rows, -1-filled past
     ``count``; ``routed_tokens`` is the compacted (⌊α·B⌋, S) gather.
+    The step's ``attention_kernel`` says which attention block it runs.
     """
     from repro.kernels.budget_route import budget_route
+    from repro.kernels.encoder_attention import encoder_attention, uses_kernel
+
+    attention = (encoder_attention if uses_kernel(force_kernel)
+                 else enc_lib.dot_attention)
 
     def route_step(enc_params_raw, tokens, mask, valid_logit):
         b = tokens.shape[0]
         pred = enc_lib.predict_accuracies(enc_params_raw, enc_cfg, tokens,
-                                          mask)                      # (B, m)
+                                          mask, attention)           # (B, m)
         imp = pred[:, expensive_idx] - pred[:, cheap_idx]
         imp = jnp.where(valid_logit < 0, CLS1_OVERRIDE, imp)
         routed_tokens, sel_idx, count = budget_route(
@@ -156,6 +166,7 @@ def make_route_step(enc_cfg: EncoderConfig, alpha: float,
             "count": count,
         }
 
+    route_step.attention_kernel = attention is encoder_attention
     return route_step
 
 

@@ -1,6 +1,8 @@
 """TPU Pallas kernels for the compute hot-spots:
 
 - flash_attention : Nougat/LM attention (the ViT inference hot loop)
+- encoder_attention: the router encoder's bidirectional attention in
+                    the route step (a whole row of keys in VMEM)
 - budget_route    : AdaParse's fused alpha-budget select+compact dispatch
 - ngram_score     : fused n-gram BLEU (the quality probe's scorer)
 - fast_features   : fused prepare stage (CLS-I features + LLM tokens)
@@ -15,5 +17,6 @@ where a block size is worth sweeping — autotune.py on the shared
 Every kernel takes ``interpret`` with no default: the ops pass
 ``interpret=(backend != "tpu")``, CPU tests pass ``interpret=True``, and
 tests/test_tpu_compile.py compiles the main-path kernels
-(budget_route, fast_features, ngram_score) for a described v5e chip.
+(budget_route, encoder_attention, fast_features, ngram_score) for a
+described v5e chip.
 """

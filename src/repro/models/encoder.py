@@ -73,7 +73,24 @@ def init_encoder(cfg: EncoderConfig, seed: int = 0, abstract: bool = False):
     }
 
 
-def _enc_layer(cfg: EncoderConfig):
+def dot_attention(q, k, v, bias, wo):
+    """The attention block as einsums: q, k, v (B, S, H, Dh), bias (B, S)
+    f32 over keys, wo (H, Dh, D) -> (B, S, D). Differentiable; the route
+    step swaps in ``kernels.encoder_attention`` (same math, scores kept
+    in VMEM)."""
+    dh = q.shape[-1]
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                   preferred_element_type=jnp.float32) * dh ** -0.5
+    # heads (12) don't divide model=16 — shard the q-seq dim of the
+    # score tensor instead (else (B,H,S,S) fp32 replicates over model)
+    s = shard_hint(s, "batch", None, "seq", None)
+    s = s + bias[:, None, None, :]
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
+    return jnp.einsum("bqhd,hdm->bqm", o, wo)
+
+
+def _enc_layer(cfg: EncoderConfig, attention=dot_attention):
     cdt = jnp.dtype(cfg.compute_dtype)
 
     def layer(carry, lp):
@@ -81,16 +98,7 @@ def _enc_layer(cfg: EncoderConfig):
         q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"].astype(cdt))
         k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"].astype(cdt))
         v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"].astype(cdt))
-        dh = q.shape[-1]
-        s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                       preferred_element_type=jnp.float32) * dh ** -0.5
-        # heads (12) don't divide model=16 — shard the q-seq dim of the
-        # score tensor instead (else (B,H,S,S) fp32 replicates over model)
-        s = shard_hint(s, "batch", None, "seq", None)
-        s = s + bias[:, None, None, :]
-        p = jax.nn.softmax(s, axis=-1).astype(cdt)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p, v)
-        o = jnp.einsum("bqhd,hdm->bqm", o, lp["wo"].astype(cdt))
+        o = attention(q, k, v, bias, lp["wo"].astype(cdt))
         x = layer_norm(x + o, lp["ln1_s"], lp["ln1_b"], cfg.norm_eps)
         h = gelu(jnp.einsum("bsd,df->bsf", x, lp["w_in"].astype(cdt))
                  + lp["b_in"].astype(cdt))
@@ -107,8 +115,10 @@ def _enc_layer(cfg: EncoderConfig):
 
 
 def encode(params_raw, cfg: EncoderConfig, tokens: jax.Array,
-           mask: jax.Array | None = None) -> jax.Array:
-    """tokens (B, S) -> pooled CLS representation (B, D)."""
+           mask: jax.Array | None = None,
+           attention=dot_attention) -> jax.Array:
+    """tokens (B, S) -> pooled CLS representation (B, D). ``attention``
+    is each layer's attention block, with ``dot_attention``'s signature."""
     cdt = jnp.dtype(cfg.compute_dtype)
     b, s = tokens.shape
     if mask is None:
@@ -119,7 +129,7 @@ def encode(params_raw, cfg: EncoderConfig, tokens: jax.Array,
                    cfg.norm_eps)
     x = shard_hint(x, "batch", "seq", "d_model")
     bias = jnp.where(mask > 0, 0.0, attn_lib.NEG_INF).astype(jnp.float32)
-    layer = _enc_layer(cfg)
+    layer = _enc_layer(cfg, attention)
     if cfg.remat:
         layer = jax.checkpoint(layer,
                                policy=jax.checkpoint_policies.nothing_saveable)
@@ -135,9 +145,10 @@ def encode(params_raw, cfg: EncoderConfig, tokens: jax.Array,
     return pooled
 
 
-def predict_accuracies(params_raw, cfg: EncoderConfig, tokens, mask=None):
+def predict_accuracies(params_raw, cfg: EncoderConfig, tokens, mask=None,
+                       attention=dot_attention):
     """(B, S) tokens -> (B, m) predicted per-parser accuracy in [0, 1]."""
-    pooled = encode(params_raw, cfg, tokens, mask)
+    pooled = encode(params_raw, cfg, tokens, mask, attention)
     out = jnp.einsum("bd,dm->bm", pooled, params_raw["head_w"].astype(pooled.dtype))
     out = out + params_raw["head_b"].astype(pooled.dtype)
     return jax.nn.sigmoid(out.astype(jnp.float32))

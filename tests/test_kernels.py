@@ -505,3 +505,89 @@ def test_f32math_matches_float64(fn, xs):
     want = getattr(np, fn)(x32.astype(np.float64))
     rel = np.abs(got - want) / np.maximum(np.abs(want), 1e-30)
     assert rel.max() < 3e-7          # a couple of float32 ulps
+
+
+# ---------------------------------------------------------------------------
+# encoder_attention: the route step's attention kernel vs the einsum block
+# ---------------------------------------------------------------------------
+
+
+def _encoder_attention_case(shape: str, rows: str):
+    """Two documents and the key bias: the first document all real, the
+    second as ``rows`` says (all real, its second half padding, only BOS
+    real). ``shape`` names (S, heads, head width, dtype): the published
+    router's bf16 12 heads of 64 over 128 or 512 positions, or the
+    reduced router's f32 4 heads of 8 over 64."""
+    from repro.models.attention import NEG_INF
+
+    s, h, d, dtype = {"s128": (128, 12, 64, jnp.bfloat16),
+                      "s512": (512, 12, 64, jnp.bfloat16),
+                      "reduced": (64, 4, 8, jnp.float32)}[shape]
+    b = 2
+    q, k, v = (jax.random.normal(jax.random.key(i), (b, s, h, d),
+                                 jnp.float32).astype(dtype)
+               for i in (1, 2, 3))
+    real = {"full": s, "half": s // 2, "bos": 1}[rows]
+    mask = np.ones((b, s), np.float32)
+    mask[1, real:] = 0
+    bias = jnp.where(jnp.asarray(mask) > 0, 0.0, NEG_INF).astype(jnp.float32)
+    return q, k, v, bias, real
+
+
+@pytest.mark.parametrize("shape", ["s128", "s512", "reduced"])
+@pytest.mark.parametrize("rows", ["full", "half", "bos"])
+def test_encoder_attention_kernel_vs_einsum(shape, rows):
+    from repro.kernels.encoder_attention.kernel import (
+        encoder_attention_kernel)
+    from repro.models.encoder import dot_attention
+
+    q, k, v, bias, real = _encoder_attention_case(shape, rows)
+    b, s, h, d = q.shape
+    got = encoder_attention_kernel(q, k, v, bias, interpret=True)
+    assert got.shape == (b, s, h * d) and got.dtype == q.dtype
+    # today's einsum block with an identity output projection is its core
+    eye = jnp.eye(h * d, dtype=q.dtype).reshape(h, d, h * d)
+    want = dot_attention(q, k, v, bias, eye)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=2e-2, rtol=2e-2)
+    # padded keys carry no weight: changing them changes nothing
+    junk = jnp.full_like(k[1:, real:], 30.0)
+    k2 = k.at[1:, real:].set(junk)
+    v2 = v.at[1:, real:].set(junk)
+    again = encoder_attention_kernel(q, k2, v2, bias, interpret=True)
+    assert np.array_equal(np.asarray(again, np.float32),
+                          np.asarray(got, np.float32))
+    if rows == "bos":     # one visible key: every query reads its value
+        np.testing.assert_array_equal(
+            np.asarray(got[1], np.float32),
+            np.broadcast_to(np.asarray(v[1, 0].reshape(-1), np.float32),
+                            (s, h * d)))
+
+
+def test_encoder_attention_block_vs_einsum_block():
+    """The op (kernel, then the output projection over merged heads)
+    against the whole einsum block, at the router's head shape."""
+    from repro.kernels.encoder_attention import encoder_attention
+    from repro.models.encoder import dot_attention
+
+    q, k, v, bias, _ = _encoder_attention_case("s128", "half")
+    _, _, h, d = q.shape
+    wo = (jax.random.normal(jax.random.key(4), (h, d, 96), jnp.float32)
+          * (h * d) ** -0.5).astype(jnp.bfloat16)
+    got = encoder_attention(q, k, v, bias, wo)
+    want = dot_attention(q, k, v, bias, wo)
+    assert got.shape == want.shape == (2, 128, 96)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=3e-2, rtol=3e-2)
+
+
+def test_encoder_attention_refuses_rows_longer_than_vmem_holds():
+    from repro.kernels.encoder_attention.kernel import (
+        MAX_SEQ, encoder_attention_kernel)
+
+    q = jnp.zeros((1, MAX_SEQ + 128, 2, 64), jnp.bfloat16)
+    bias = jnp.zeros((1, MAX_SEQ + 128), jnp.float32)
+    with pytest.raises(ValueError, match=f"> {MAX_SEQ}"):
+        encoder_attention_kernel(q, q, q, bias, interpret=True)

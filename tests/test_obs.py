@@ -314,6 +314,22 @@ def test_engine_off_plane_records_and_observes_nothing(tiny_llm):
         lookup.cached = True             # ignored, never raises
 
 
+@pytest.mark.parametrize("kernel", [False, True])
+def test_route_attention_kernel_counted_once_per_batch(tiny_llm, monkeypatch,
+                                                       kernel):
+    """``route.attention_kernel`` counts the batches whose route step ran
+    the encoder attention kernel, plane on or off: none on the einsum path
+    (this backend's), each of the 3 when the kernel is taken (interpret
+    mode here)."""
+    import repro.kernels.encoder_attention as ea
+
+    if kernel:
+        monkeypatch.setattr(ea, "uses_kernel", lambda force_kernel=False: True)
+    _, metrics = _run_tiny(tiny_llm, False)
+    assert metrics["counters"].get(obs.ROUTE_ATTENTION_KERNEL, 0) == \
+        (3 if kernel else 0)
+
+
 def test_plane_hooks_gc_and_compiles_until_turned_off():
     import jax
     import jax.numpy as jnp

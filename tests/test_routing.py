@@ -165,6 +165,47 @@ def test_route_step_device_vs_host_mirror():
     assert (imp[valid < 0] == CLS1_OVERRIDE).all()
 
 
+def test_route_step_attention_kernel_matches_einsum_path():
+    """The route step with the encoder attention kernel forced (interpret
+    mode here) selects exactly what the einsum path selects, and its
+    predicted accuracies agree within bf16 rounding."""
+    from repro.common import unwrap
+    from repro.configs.base import EncoderConfig
+    from repro.core.router import make_route_step
+    from repro.models import encoder as enc_lib
+
+    cfg = EncoderConfig(name="t", n_layers=2, d_model=32, n_heads=2,
+                        d_ff=64, vocab_size=64, max_len=16,
+                        param_dtype="float32", compute_dtype="bfloat16")
+    params = unwrap(enc_lib.init_encoder(cfg, 0))
+    rng = np.random.RandomState(0)
+    b = 40
+    toks = jnp.asarray(rng.randint(2, 64, (b, 16)).astype(np.int32))
+    mask = np.ones((b, 16), np.float32)
+    mask[::3, 9:] = 0                         # a third of the rows padded
+    mask[1, 1:] = 0                           # one row of BOS alone
+    valid = jnp.ones((b,), jnp.float32)       # the encoder ranks every row
+    # centre the head so half the rows gain from the expensive parser
+    # (index 2 over index 0): the top-4 is then a real ranking
+    pooled = enc_lib.encode(params, cfg, toks, jnp.asarray(mask))
+    z = np.asarray(pooled.astype(jnp.float32) @ params["head_w"])
+    params["head_b"] = params["head_b"].at[2].add(
+        float(np.median(z[:, 0] - z[:, 2])))
+    einsum_step = make_route_step(cfg, alpha=0.1)
+    kernel_step = make_route_step(cfg, alpha=0.1, force_kernel=True)
+    assert kernel_step.attention_kernel and not einsum_step.attention_kernel
+    want = jax.jit(einsum_step)(params, toks, jnp.asarray(mask), valid)
+    got = jax.jit(kernel_step)(params, toks, jnp.asarray(mask), valid)
+    assert int(want["count"]) == 4 and \
+        np.sum(np.asarray(want["improvement"]) > 0) > 8
+    np.testing.assert_array_equal(np.asarray(got["selected_idx"]),
+                                  np.asarray(want["selected_idx"]))
+    assert int(got["count"]) == int(want["count"])
+    np.testing.assert_allclose(np.asarray(got["pred_acc"]),
+                               np.asarray(want["pred_acc"]),
+                               atol=1e-2, rtol=0)
+
+
 def test_ties_never_displace_strictly_better():
     """A strictly higher-scoring doc is always routed, even when tied
     lower scores fill the batch ahead of it in row order (host, ref, and

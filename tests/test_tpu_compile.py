@@ -9,6 +9,8 @@ import: only one process may load the TPU library, and under pytest-
 xdist every worker imports every test file. Keep these tests in this
 one file so that one worker loads it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -16,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.budget_route.kernel import budget_route_kernel
 from repro.kernels.budget_route.ops import capacity_floor
+from repro.kernels.encoder_attention.kernel import encoder_attention_kernel
 from repro.kernels.fast_features.kernel import fast_features_kernel
 from repro.kernels.ngram_score.kernel import ngram_bleu_kernel
 
@@ -92,27 +95,111 @@ def test_ngram_score_compiles_for_v5e(one_chip, no_compile_cache):
     _assert_kernel(compiled)
 
 
-def test_published_route_step_compiles_with_kernel(one_chip,
-                                                   no_compile_cache,
-                                                   monkeypatch):
-    """The jitted route step at the published adaparse-router widths
-    (12 layers, d=768, 512 tokens) on the App. C batch k=256, with the
-    budget_route kernel in it. Dispatch asks ``jax.default_backend()``,
-    which still says cpu here, so the test steers it to the TPU branch."""
+def test_encoder_attention_compiles_for_v5e(one_chip, no_compile_cache):
+    """The route step's attention at the router's shape: 256 documents,
+    12 heads of 64 over 512 positions."""
+    b, s, h, d = 256, 512, 12, 64
+    qkv = _sds((b, s, h, d), jnp.bfloat16, one_chip)
+    compiled = encoder_attention_kernel.lower(
+        qkv, qkv, qkv, _sds((b, s), jnp.float32, one_chip),
+        interpret=False).compile()
+    _assert_kernel(compiled)
+
+
+def _route_step(which: str, b: int, one_chip, monkeypatch):
+    """The jitted route step of the ``which`` router encoder on a batch
+    of ``b``, compiled for the described chip. Dispatch asks
+    ``jax.default_backend()``, which still says cpu here, so the step is
+    steered to its TPU branch (the kernels)."""
     from repro.common import unwrap
     from repro.core.router import make_route_step
     from repro.launch.serve import router_encoder_config
     from repro.models import encoder as enc_lib
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = router_encoder_config("published")
-    assert (cfg.n_layers, cfg.d_model, cfg.max_len) == (12, 768, 512)
+    cfg = router_encoder_config(which)
     params = jax.tree_util.tree_map(
         lambda a: _sds(a.shape, a.dtype, one_chip),
         jax.eval_shape(lambda: unwrap(enc_lib.init_encoder(cfg, 0))))
-    b = 256
-    compiled = jax.jit(make_route_step(cfg, 0.05)).lower(
+    step = make_route_step(cfg, 0.05)
+    assert step.attention_kernel
+    compiled = jax.jit(step).lower(
         params, _sds((b, cfg.max_len), jnp.int32, one_chip),
         _sds((b, cfg.max_len), jnp.float32, one_chip),
         _sds((b,), jnp.float32, one_chip)).compile()
+    return cfg, compiled
+
+
+def _hlo_computation(hlo: str, name: str) -> str:
+    """The body of the HLO computation ``name``."""
+    (body,) = re.findall(rf"^%{re.escape(name)} [^\n]*\{{\n(.*?)^\}}",
+                         hlo, re.M | re.S)
+    return body
+
+
+def _hlo_defines(hlo: str, name: str) -> str:
+    """The line that defines the instruction ``name``."""
+    (line,) = re.findall(rf"^\s*(?:ROOT )?{re.escape(name)} = .*$", hlo, re.M)
+    return line
+
+
+def _fused_ops(hlo: str, line: str) -> str:
+    """Every instruction a fusion runs, through nested fusions."""
+    bodies, todo = [], re.findall(r"calls=%([\w.\-]+)", line)
+    while todo:
+        body = _hlo_computation(hlo, todo.pop())
+        bodies.append(body)
+        todo += re.findall(r"calls=%([\w.\-]+)", body)
+    return "\n".join(bodies)
+
+
+@pytest.mark.parametrize("b", [32, 256])
+def test_reduced_route_step_compiles_with_kernel(one_chip, no_compile_cache,
+                                                 monkeypatch, b):
+    """``serve``'s default router (2 layers, d=32, 4 heads of 8 over 64
+    tokens, f32) takes the attention kernel on a TPU too: its 8-lane head
+    slices and K=8 contractions compile for the chip."""
+    cfg, compiled = _route_step("reduced", b, one_chip, monkeypatch)
+    assert (cfg.n_heads, cfg.d_model // cfg.n_heads, cfg.max_len) == \
+        (4, 8, 64)
     _assert_kernel(compiled)
+    assert "encoder_attention_kernel" in compiled.as_text()
+
+
+def test_published_route_step_compiles_with_kernel(one_chip,
+                                                   no_compile_cache,
+                                                   monkeypatch):
+    """The jitted route step at the published adaparse-router widths
+    (12 layers, d=768, 512 tokens) on the App. C batch k=256, with the
+    encoder attention and budget_route kernels in it. The layers' f32
+    score tensor, (k, 12, 512, 512), no longer goes to HBM: the step's
+    temporaries are under half of one such tensor (they were 4.03 GB
+    with it)."""
+    b = 256
+    cfg, compiled = _route_step("published", b, one_chip, monkeypatch)
+    assert (cfg.n_layers, cfg.d_model, cfg.max_len) == (12, 768, 512)
+    _assert_kernel(compiled)
+    hlo = compiled.as_text()
+    call = re.search(r"%(encoder_attention_kernel[.\d]*) = \S+ "
+                     r"custom-call\(([^)]*)\), custom_call_target="
+                     r"\"tpu_custom_call\"", hlo)
+    assert call
+    # the q/k/v projections write the rows the kernel reads, and `wo`
+    # reads the rows it writes: no copy or transpose of a (k, 512, 768)
+    # tensor around the call, in those fusions or between them and it
+    rows = f"bf16[{b},{cfg.max_len},{cfg.d_model}]"
+    q, k, v, _ = call.group(2).split(", ")
+    for operand in (q, k, v):
+        line = _hlo_defines(hlo, operand)
+        assert line.split(" = ")[1].startswith(rows) and " fusion(" in line
+        ops = _fused_ops(hlo, line)
+        assert "bsd,dhk->bshk/dot_general" in ops, line
+        assert not re.search(r" (copy|transpose)\(", ops), line
+    (user,) = [line for line in hlo.splitlines()
+                if re.search(rf"[(, ]%{re.escape(call.group(1))}[,)]", line)]
+    assert " fusion(" in user
+    ops = _fused_ops(hlo, user)
+    assert "bsm,md->bsd/dot_general" in ops, user
+    assert not re.search(r" (copy|transpose)\(", ops), user
+    scores = b * cfg.n_heads * cfg.max_len ** 2 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < scores / 2
