@@ -42,7 +42,8 @@ def control(config: dict, traffic: dict, seed: int) -> dict:
             BENCH / "configs" / f"{config['reference']}.py")
         weights = harness.seeded_weights(config, encoder, seed, stages)
     ref = check.Reference(config, traffic, T.stream_seed(seed, 4),
-                          T.stream_seed(seed, 5), stages, weights, encoder)
+                          T.stream_seed(seed, 5), stages, weights, encoder,
+                          harness.parser_model(config, seed))
     pool = T.make_pool(traffic, traffic["corpus"], seed, Document)
     return check.control_numbers(
         ref, T.batches(pool, config["batch_size"], seed))

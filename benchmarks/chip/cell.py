@@ -10,9 +10,16 @@ loop for ``--seconds``, compare a seeded sample of what the window
 emitted with the plain reference, then print one JSON line:
 ``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's
 end-to-end metrics, or with ``--trace 1`` its per-layer ones, read
-under the profiler), ``device``, with ``--trace 1`` ``breakdown``, and
-last ``checks``: each compared number beside its limit. The same
-numbers are the last lines on standard error.
+under the profiler, with the program's own spans and counters on for
+the window), ``device``, with ``--trace 1`` ``breakdown`` (with
+``idle_by_span``: the device's idle seconds by the program span that
+held it up), and last ``checks``: each compared number beside its
+limit. The same numbers are the last lines on standard error.
+
+A configuration that names a ``parser_model`` brings its module under
+``configs/``, which builds the parser's weights, hands the engine the
+program's backend for it, and adds its own numbers to ``checks``; the
+hooks are listed in ``harness.py``.
 
 ``--workload`` names an entry of ``BENCHMARK.json``; ``--cell
 CONFIG/TRAFFIC`` runs a configuration and a traffic mix that have no

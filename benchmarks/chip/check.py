@@ -23,8 +23,10 @@ and every stage is compared:
 - ``probe_gap``     largest per-parser mean BLEU difference of a probed
                     batch
 
-Each number has its limit in the configuration file; the run is correct
-when every number is at or under its limit.
+A configuration with a parser model adds the numbers its module's
+``readings`` gives for each sampled batch (``harness`` lists the
+hooks). Each number has its limit in the configuration file; the run is
+correct when every number is at or under its limit.
 """
 from __future__ import annotations
 
@@ -45,6 +47,8 @@ def program_sample(row: dict, variant: str) -> dict:
          "selected": np.asarray(row["plan"].expensive_idx, np.int64),
          "records": [(r.parser, r.pages) for r in row["records"]],
          "quality": row.get("quality")}
+    if "parser" in row:
+        s["parser"] = row["parser"]
     if variant == "llm":
         s.update(tokens=np.asarray(prep.route_host["tokens"]),
                  mask=np.asarray(prep.route_host["mask"]),
@@ -55,14 +59,20 @@ def program_sample(row: dict, variant: str) -> dict:
 
 class Reference:
     """The plain reference of one cell: its configuration, corpus,
-    routing stages and weights, fixed for the run."""
+    routing stages and weights, and its parser model
+    (``harness.ParserModel``) where it has one, fixed for the run."""
 
     def __init__(self, config: dict, traffic: dict, engine_seed: int,
-                 probe_seed: int, stages: dict, weights=None, encoder=None):
+                 probe_seed: int, stages: dict, weights=None, encoder=None,
+                 parser=None):
         self.cfg, self.traffic = config, traffic
         self.corpus = traffic["corpus"]
         self.engine_seed, self.probe_seed = engine_seed, probe_seed
         self.stages, self.weights, self.encoder = stages, weights, encoder
+        self.parser = parser
+        #: the numbers summed over batches; the others fold by the max
+        self.exact = EXACT + (tuple(parser.hook("EXACT") or ())
+                              if parser is not None else ())
 
     def batch(self, docs, key: int, precision: str = "exact",
               selected: np.ndarray | None = None) -> dict:
@@ -153,15 +163,20 @@ def compare(prog: dict, ref: Reference) -> dict:
                                != {p: n for p, (_, n) in exp.items()})
         r["probe_gap"] = max(abs(got[p][0] - exp[p][0])
                              for p in got.keys() & exp.keys())
+    readings = ref.parser and ref.parser.hook("readings")
+    if readings is not None:
+        r.update(readings(prog, ref.parser.weights, ref.parser.widths))
     return r
 
 
-def fold(readings: list[dict], window_probe_diff: int) -> dict:
-    """One run's numbers: exact counts summed, gaps at their largest."""
+def fold(readings: list[dict], window_probe_diff: int,
+         exact: tuple = EXACT) -> dict:
+    """One run's numbers: the ``exact`` counts summed, gaps at their
+    largest."""
     out: dict = {}
     for r in readings:
         for name, v in r.items():
-            if name in EXACT:
+            if name in exact:
                 out[name] = out.get(name, 0) + v
             else:
                 out[name] = max(out.get(name, 0.0), v)
@@ -197,4 +212,4 @@ def control_numbers(ref: Reference, batches, per_stratum: int = 2,
             readings.append(compare(ref.batch(docs, key, "control"), ref))
         if not any(want.values()):
             break
-    return fold(readings, 0)
+    return fold(readings, 0, ref.exact)

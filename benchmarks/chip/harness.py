@@ -10,15 +10,48 @@ drives ``AdaParseEngine._overlapped_batches`` — the loop
 ``AdaParseEngine.run`` uses — over an endless stream of fresh batch
 keys; the stages are timed by wrapping the methods of this run's own
 engine instance, never by editing the program.
+
+A configuration may bring its own parser model: ``"parser_model":
+{"reference": "<module>", ...widths...}`` names ``configs/<module>.py``
+and gives it the other keys as ``widths``. That module builds, runs and
+checks the model; the harness calls it and nothing else knows of it:
+
+- ``init(widths, seed)``: the seeded weights, made on the device
+  (required);
+- ``backend(widths, weights, name)``: the program's parser backend,
+  registered under the configuration's ``expensive`` parser for the
+  run, before the engine is built, so that the engine's own
+  ``complete_batch`` runs it (required);
+- ``warm(backend)``: compile what the window's batches run, in set-up;
+- ``keep(backend, row)``: after each ``complete_batch``, what to keep
+  of that batch for the check; stored as ``row["parser"]`` and dropped
+  with the batch's other heavy outputs when it is not sampled;
+- ``readings(sample, weights, widths) -> {name: number}``: for every
+  sampled batch, the module's numbers against its own reference
+  (``sample["parser"]`` is what ``keep`` returned); names in the
+  module's ``EXACT`` are summed over the batches, the others folded by
+  their largest, and each is judged by its limit in the configuration's
+  ``limits``. The control's samples (``calibrate.py``) carry no
+  ``parser``: there the module puts its reference one precision lower
+  in the program's place.
+
+A traced run (``trace``) turns the program's observability plane on for
+the window alone, reads its spans from the profiler's trace
+(``spantrace``) into ``Run.spans`` and ``breakdown["idle_by_span"]``,
+and gives ``Run.counters``: the program's counters' change over the
+window.
 """
 from __future__ import annotations
 
 import contextlib
+import dataclasses
+import functools
 import importlib.util
 import json
 import pathlib
 import shutil
 import sys
+import tempfile
 import time
 
 import numpy as np
@@ -38,6 +71,9 @@ WARM_BATCHES = 2
 WINDOW_KEY = 1 << 20
 #: window batches kept for the reference, per stratum (probed or not)
 CHECK_BATCHES = 2
+#: the parser model's weights' stream (streams 6 and 7 seed the two
+#: strata's samples)
+PARSER_STREAM = 8
 
 
 def load_json(kind: str, name: str) -> dict:
@@ -147,6 +183,35 @@ def _joined(pages):
             else np.zeros(0, np.int32))
 
 
+@dataclasses.dataclass
+class ParserModel:
+    """The parser model a configuration names: its module, the widths
+    it is built at, and its seeded weights."""
+
+    module: object
+    widths: dict
+    weights: object
+
+    def hook(self, name: str):
+        """The module's optional hook ``name``, or None."""
+        return getattr(self.module, name, None)
+
+
+def parser_model(config: dict, seed: int) -> ParserModel | None:
+    """The configuration's ``parser_model``, built from the seed, or None
+    for a configuration without one."""
+    import jax
+
+    spec = config.get("parser_model")
+    if spec is None:
+        return None
+    module = load_module(BENCH / "configs" / f"{spec['reference']}.py")
+    widths = {k: v for k, v in spec.items() if k != "reference"}
+    weights = jax.block_until_ready(
+        module.init(widths, T.stream_seed(seed, PARSER_STREAM)))
+    return ParserModel(module, widths, weights)
+
+
 def build_router(config: dict, stages: dict, weights):
     from repro.configs.base import EncoderConfig
     from repro.core.router import AdaParseRouter, LinearStage
@@ -164,10 +229,12 @@ class Instrument:
     stage call of one engine instance, per batch key."""
 
     def __init__(self, engine, tracing: bool, probe_len: int, cls1,
-                 valid_threshold: float):
+                 valid_threshold: float, keep=None):
         import jax
 
         self.probe_len = probe_len
+        #: the parser model's ``keep`` hook, bound to its backend
+        self.keep = keep
         self.cls1, self.valid_threshold = cls1, valid_threshold
         self.rows: dict[int, dict] = {}
         self.current: int | None = None
@@ -246,6 +313,8 @@ class Instrument:
                        records=records,
                        complete_ok=[r.doc_id for r in records]
                        == [d.doc_id for d in prep.docs])
+            if self.keep is not None:
+                row["parser"] = self.keep(row)
             self.completed = key
             return records
         return complete_batch
@@ -286,12 +355,15 @@ class Reservoir:
         return False, []
 
 
-def warm_shapes(engine, config: dict, traffic: dict, pool: list) -> None:
+def warm_shapes(engine, config: dict, traffic: dict, pool: list,
+                warm_parser=None) -> None:
     """Compile (or load from the cache) every program the window's
     batches use, beyond the route step that the first batches compile:
-    the prepare stage at each packed width the mix produces, and the
-    probe's scorer at every padded group size a batch of k with at most
-    floor(alpha*k) expensive records can give."""
+    the prepare stage at each packed width the mix produces, the parser
+    model's programs (its ``warm`` hook, bound to its backend, as
+    ``warm_parser``), and the probe's scorer at every padded group size
+    a batch of k with at most floor(alpha*k) expensive records can
+    give."""
     import jax
 
     from repro.core import features as F
@@ -306,6 +378,8 @@ def warm_shapes(engine, config: dict, traffic: dict, pool: list) -> None:
         out = F.prepare_routing_inputs(pages, engine.ccfg, max_len=max_len,
                                        mode=engine.cfg.feature_kernel)
         jax.block_until_ready([o for o in out if o is not None])
+    if warm_parser is not None:
+        warm_parser()
     if engine.probe is None:
         return
     pads, docs = set(), pool[:k]
@@ -335,15 +409,19 @@ class Run:
     ``calls`` are the rows of every batch the run instrumented, with the
     host clock's start of each stage call (``t_prepare``,
     ``t_complete``) and the sizes it saw; ``trace`` is the reduced
-    profiler trace (``devtrace.Reduced``) or None."""
+    profiler trace (``devtrace.Reduced``) or None; ``spans`` the
+    program's own spans in it (``spantrace.Spans``) or None; and
+    ``counters`` the change of the program's counters
+    (``repro.core.obs``) over a traced window, by name."""
 
     def __init__(self, config, traffic, batches, calls, window, compiles,
-                 peak, trace):
+                 peak, trace, spans=None, counters=None):
         self.config, self.traffic = config, traffic
         self.batches, self.calls = batches, calls
         self.t0, self.t_end = window
         self.window_s = self.t_end - self.t0
         self.compiles, self.peak, self.trace = compiles, peak, trace
+        self.spans, self.counters = spans, counters or {}
 
     def started(self, stage: str) -> list[dict]:
         """Rows whose ``stage`` call started inside the window."""
@@ -378,21 +456,62 @@ def run_cell(name, config, traffic, e2e, per_layer, *, seed: int,
              seconds: float, trace: bool, t_start: float,
              keep_trace: pathlib.Path | None = None) -> dict:
     """Set up, warm up, measure for ``seconds``, check, and return the
-    result line's object."""
+    result line's object. A parser model's backend stands under the
+    configuration's ``expensive`` name from before the engine is built
+    until the window has closed."""
     import jax
 
-    from repro.core.engine import AdaParseEngine, EngineConfig
-    from repro.core.quality import QualityProbe, QualityProbeConfig
-    from repro.data.synthetic import Document
     from repro.device import ensure_compile_cache
-
-    import check
-    import devtrace
 
     # every program, however quick to compile, goes to the cache, so a
     # second run of the cell compiles nothing
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     log(f"{name} seed {seed}: compile cache {ensure_compile_cache()}")
+    parser = parser_model(config, seed)
+    with contextlib.ExitStack() as program:
+        backend = None
+        if parser is not None:
+            backend = parser.module.backend(parser.widths, parser.weights,
+                                            name=config["expensive"])
+            program.enter_context(standing_in(backend, config["expensive"]))
+        return _run_cell(name, config, traffic, e2e, per_layer, parser,
+                         backend, program.close, seed=seed, seconds=seconds,
+                         trace=trace, t_start=t_start, keep_trace=keep_trace)
+
+
+@contextlib.contextmanager
+def standing_in(backend, name: str):
+    """``backend`` registered under ``name`` in the program's registry of
+    parser backends, the one it replaced restored on exit."""
+    from repro.core import backends
+
+    if backend.info.name != name:
+        raise ValueError(f"the parser model's backend is named "
+                         f"{backend.info.name!r}, not {name!r}")
+    before = backends.get_backend(name)
+    backends.register_backend(backend, overwrite=True)
+    try:
+        yield backend
+    finally:
+        backends.register_backend(before, overwrite=True)
+
+
+def _run_cell(name, config, traffic, e2e, per_layer, parser, backend,
+              release, *, seed, seconds, trace, t_start, keep_trace) -> dict:
+    """``run_cell`` past the compile cache and the parser model: the
+    engine, warm-up, window and check; ``release()`` gives the parser
+    backend's place back once the window has closed."""
+    import jax
+
+    from repro.core import obs
+    from repro.core.engine import AdaParseEngine, EngineConfig
+    from repro.core.quality import QualityProbe, QualityProbeConfig
+    from repro.data.synthetic import Document
+
+    import check
+    import devtrace
+    import spantrace
+
     compiled: list[tuple[float, str]] = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda ev, dur, **kw: compiled.append(
@@ -425,9 +544,13 @@ def run_cell(name, config, traffic, e2e, per_layer, *, seed: int,
             max_len=traffic["probe_max_len"], metric="bleu")))
     log(f"inputs, routing stages and weights {time.perf_counter() - t:.2f} s")
     t = time.perf_counter()
-    warm_shapes(engine, config, traffic, pool)
+    hooks = {h: functools.partial(parser.hook(h), backend)
+             for h in ("warm", "keep")
+             if parser is not None and parser.hook(h) is not None}
+    warm_shapes(engine, config, traffic, pool, hooks.get("warm"))
     inst = Instrument(engine, trace, traffic["probe_max_len"],
-                      stages["cls1"], config["valid_threshold"])
+                      stages["cls1"], config["valid_threshold"],
+                      hooks.get("keep"))
     max_len = config["encoder"]["max_len"] if llm else 0
     warm = engine._overlapped_batches(
         T.batches(pool, config["batch_size"], seed), 0)
@@ -447,9 +570,12 @@ def run_cell(name, config, traffic, e2e, per_layer, *, seed: int,
         f"compiled or loaded; compile cache hits {cache[CACHE_HIT]}, "
         f"misses {cache[CACHE_MISS]}")
 
-    trace_dir = BENCH / "out" / "trace"
     if trace:
-        shutil.rmtree(trace_dir, ignore_errors=True)
+        # a directory of the run's own: runs in other processes trace
+        # beside it
+        (BENCH / "out").mkdir(parents=True, exist_ok=True)
+        trace_dir = pathlib.Path(tempfile.mkdtemp(prefix="trace-",
+                                                  dir=BENCH / "out"))
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 1
@@ -457,6 +583,11 @@ def run_cell(name, config, traffic, e2e, per_layer, *, seed: int,
     samples = {s: Reservoir(CHECK_BATCHES, T.stream_seed(seed, 6 + s))
                for s in (0, 1)}
     counted: list[int] = []
+    counters: dict = {}
+    if trace:
+        # the program's spans and counters, for the traced window alone
+        obs.configure(True)
+        base = obs.metrics().snapshot()
     with inst.span("bench.window_open"):
         t0 = time.perf_counter()
     n_compiled = len(compiled)
@@ -478,9 +609,11 @@ def run_cell(name, config, traffic, e2e, per_layer, *, seed: int,
                 break
     finally:
         gen.close()
+        if trace:
+            jax.profiler.stop_trace()
+            counters = obs.diff(obs.metrics().snapshot(), base)["counters"]
+            obs.configure(False)
     window_s = t_end - t0
-    if trace:
-        jax.profiler.stop_trace()
     window_compiles = [f for c, f in compiled[n_compiled:] if c <= t_end]
     stats = dev.memory_stats() or {}
     rows = [inst.rows[k] for k in counted]
@@ -504,16 +637,19 @@ def run_cell(name, config, traffic, e2e, per_layer, *, seed: int,
 
     t = time.perf_counter()
     ref = check.Reference(config, traffic, engine_seed, probe_seed, stages,
-                          weights, encoder)
+                          weights, encoder, parser)
     sampled = [k for s in samples.values() for k in s.items]
     progs = [check.program_sample(inst.rows[k], config["variant"])
              for k in sampled]
-    del engine, router, gen
+    del engine, router, gen, backend, hooks
+    inst.keep = None
+    release()
     for r in inst.rows.values():
         _forget(r)
     probe_diff = sum(("quality" in r) != ref.probed(k)
                      for k, r in zip(counted, rows))
-    numbers = check.fold([check.compare(p, ref) for p in progs], probe_diff)
+    numbers = check.fold([check.compare(p, ref) for p in progs], probe_diff,
+                         ref.exact)
     correct, checks = check.judge(numbers, config["limits"])
     correct = correct and failed == 0 and docs > 0
     log(f"reference over {len(progs)} batches {time.perf_counter() - t:.2f} s")
@@ -534,11 +670,14 @@ def run_cell(name, config, traffic, e2e, per_layer, *, seed: int,
         if keep_trace is not None and files:
             keep_trace.mkdir(parents=True, exist_ok=True)
             shutil.copy(files[-1], keep_trace / files[-1].name)
-        reduced = (devtrace.reduce(devtrace.load(files[-1]), window_s)
-                   if files else None)
+        events = devtrace.load(files[-1]) if files else []
+        reduced = devtrace.reduce(events, window_s) if files else None
+        spans = (spantrace.reduce(events + spantrace.load(files[-1]),
+                                  window_s) if files else None)
         shutil.rmtree(trace_dir, ignore_errors=True)
         run = Run(config, traffic, rows, list(inst.rows.values()),
-                  (t0, t_end), len(window_compiles), peak, reduced)
+                  (t0, t_end), len(window_compiles), peak, reduced, spans,
+                  counters)
         result["metrics"] = {}
         for m in per_layer:
             v = metric_reader(m["name"])(run)
@@ -549,6 +688,9 @@ def run_cell(name, config, traffic, e2e, per_layer, *, seed: int,
             device.update(busy_s=reduced.busy_s, window_s=reduced.window_s)
             result["device"] = device
             result["breakdown"] = reduced.breakdown
+            if spans is not None:
+                result["breakdown"]["idle_by_span"] = devtrace._top(
+                    spans.idle_by_span)
         else:
             result["device"] = device
     result["checks"] = checks
@@ -578,7 +720,7 @@ def routing_summary(rows: list[dict]) -> str:
             f"with a positive improvement {stat(positive)}")
 
 
-HEAVY = ("prep", "plan", "records", "route_out")
+HEAVY = ("prep", "plan", "records", "route_out", "parser")
 
 
 def _forget(row: dict) -> None:

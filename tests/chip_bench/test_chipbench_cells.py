@@ -51,10 +51,12 @@ def test_cell_runs_at_a_tiny_size(workload, trace, interpret_kernels):
     assert set(res["checks"]) == set(config["limits"])
     got = set(res["metrics"])
     if trace:
-        # host-clock readers read on any backend; the device ones find
-        # no TPU trace on the CPU and stay silent
+        # host-clock and program-span readers read on any backend; the
+        # device ones find no TPU trace on the CPU and stay silent
         assert got == {"window_compiles", "prepare_ms", "route_ms",
-                       "complete_ms", "probe_ms"}
+                       "complete_ms", "probe_ms", "prefetch_wait_ms",
+                       "prepare_host_ms", "prepare_wait_ms",
+                       "route_wait_ms", "gc_ms", "starved_frac"}
         assert res["metrics"]["window_compiles"]["value"] == 0
     else:
         assert got == {m["name"] for m in e2e}

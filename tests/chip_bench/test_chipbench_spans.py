@@ -1,11 +1,13 @@
 """The program's spans in a profiler trace (``spantrace``): loading them
 with their threads, clipping them to the window, charging each idle gap
 of the device to the span that held it up, and the metric readers that
-read them, by hand; and silence where a run has no program spans."""
+read them, by hand and on a trace recorded on a TPU v5e chip; and
+silence where a run has no program spans."""
+import json
 import threading
 
 import pytest
-from chipbench_tiny import BENCH  # noqa: F401  (the harness on the path)
+from chipbench_tiny import BENCH
 
 import devtrace
 import harness
@@ -141,3 +143,39 @@ def test_load_keeps_program_spans_with_their_threads(tmp_path):
     by = {e.name: e for e in events}
     assert by["adaparse.route"].start_ns <= by["adaparse.route.wait"].start_ns
     assert by["adaparse.route.wait"].end_ns <= by["adaparse.route"].end_ns
+
+
+def test_reduction_on_a_recorded_trace_with_program_spans():
+    rec = json.loads((BENCH / "testdata" / "trace_excerpt_spans.json")
+                     .read_text())
+    events = [devtrace.Event(*e[:5], tuple(map(tuple, e[5])))
+              for e in rec["events"]]
+    got = spantrace.reduce(events, rec["seconds"])
+    want = rec["expected"]
+    assert got.window_s == pytest.approx(rec["seconds"])
+    assert got.idle_by_span == pytest.approx(want["idle_by_span"],
+                                             rel=1e-9)
+    assert {n: got.total_s(n) for n in got.spans} == pytest.approx(
+        want["span_s"], rel=1e-9)
+    assert got.idle_under_s("prefetch.wait") == pytest.approx(
+        want["idle_under_prefetch_wait_s"], rel=1e-9)
+    # the consumer's and the prefetch thread's lines told apart: the
+    # route step's wait and the prefetch thread's prepare on two threads
+    threads = {n: {t for _, _, t in ivs} for n, ivs in got.spans.items()}
+    assert threads["route.wait"] == threads["prefetch.wait"] \
+        != threads["prepare"] == threads["prepare.wait"]
+    # every idle second of the device reduction of the same events is
+    # charged to some span
+    dev = devtrace.reduce(events, rec["seconds"])
+    assert dev.busy_s == pytest.approx(want["busy_s"], rel=1e-9)
+    assert sum(got.idle_by_span.values()) == pytest.approx(
+        got.window_s - dev.busy_s, rel=1e-9)
+    run = _run(got, n_batches=2)
+    read = {m: harness.metric_reader(m)(run) for m in READERS}
+    assert read["route_wait_ms"] == pytest.approx(
+        1e3 * want["span_s"]["route.wait"] / 2)
+    assert read["prepare_host_ms"] + read["prepare_wait_ms"] == \
+        pytest.approx(1e3 * want["span_s"]["prepare"] / 2)
+    assert read["gc_ms"] == 0.0
+    assert read["starved_frac"] == pytest.approx(
+        want["idle_under_prefetch_wait_s"] / rec["seconds"])
