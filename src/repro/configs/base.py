@@ -114,45 +114,57 @@ class EncoderConfig:
 
 
 @dataclasses.dataclass(frozen=True)
-class VitParserConfig:
-    """Nougat-class parser: windowed-attention image encoder + causal
-    cross-attention text decoder. Page pixels -> patch embeddings is a
-    stub frontend (input_specs provides patch embeddings directly)."""
+class NougatConfig:
+    """Nougat (Blecher et al., arXiv:2308.13418), the expensive parser: a
+    Swin encoder (arXiv:2103.14030) over the page image and an mBART
+    decoder (arXiv:2001.08210) that cross-attends to the encoder's last
+    stage. The serving fields size the decode: ``decode_slots`` pages
+    decode side by side, each over a self-attention cache of
+    ``cache_len`` positions read in blocks of ``kv_block``."""
 
     name: str
-    # encoder (Swin-ish, single resolution for simplicity at scale)
-    enc_layers: int
-    enc_d_model: int
-    enc_heads: int
-    enc_d_ff: int
-    window: int                       # window size in patches (1D-flattened windows)
+    # encoder (Swin)
     image_hw: tuple[int, int] = (896, 672)
-    patch: int = 16
-    # decoder (mBART-ish causal LM with cross attention)
+    patch: int = 4
+    embed_dim: int = 128
+    depths: tuple[int, ...] = (2, 2, 14, 2)
+    heads: tuple[int, ...] = (4, 8, 16, 32)
+    window: int = 7
+    mlp_ratio: int = 4
+    # decoder (mBART)
     dec_layers: int = 10
     dec_d_model: int = 1024
     dec_heads: int = 16
     dec_d_ff: int = 4096
     vocab_size: int = 50000
-    max_dec_len: int = 4096
-    pages_per_batch: int = 10         # paper's B_p
-    norm_eps: float = 1e-5
+    max_positions: int = 4096
+    # what the published description leaves open (PERF.md lists them)
+    pos_offset: int = 2               # mBART's learned-position offset
+    scale_embedding: bool = True      # token embeddings times sqrt(d)
+    norm_eps: float = 1e-5            # every LayerNorm
+    bos_id: int = 0
+    glyph_cell: tuple[int, int] = (28, 24)   # page pixels per rendered token
+    # serving
+    decode_slots: int = 96
+    cache_len: int = 896
+    kv_block: int = 128
+    encode_chunk: int = 8
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
-    remat: bool = True
-    scan_layers: bool = True
+
+    def grid(self, stage: int) -> tuple[int, int]:
+        """Token grid (rows, columns) of encoder stage ``stage``."""
+        f = self.patch * 2 ** stage
+        return self.image_hw[0] // f, self.image_hw[1] // f
+
+    def width(self, stage: int) -> int:
+        return self.embed_dim * 2 ** stage
 
     @property
-    def n_patches(self) -> int:
-        return (self.image_hw[0] // self.patch) * (self.image_hw[1] // self.patch)
-
-    def n_params(self) -> int:
-        e = self.enc_layers * (4 * self.enc_d_model**2
-                               + 2 * self.enc_d_model * self.enc_d_ff)
-        d = self.dec_layers * (8 * self.dec_d_model**2
-                               + 2 * self.dec_d_model * self.dec_d_ff)
-        emb = self.vocab_size * self.dec_d_model + self.n_patches * self.enc_d_model
-        return e + d + emb
+    def enc_tokens(self) -> int:
+        """Encoder tokens per page that the decoder cross-attends to."""
+        h, w = self.grid(len(self.depths) - 1)
+        return h * w
 
 
 @dataclasses.dataclass(frozen=True)
@@ -235,7 +247,7 @@ class ShapeConfig:
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     arch_id: str
-    family: str                       # "lm" | "gnn" | "recsys" | "encoder" | "vit_parser"
+    family: str                       # "lm" | "gnn" | "recsys" | "encoder" | "nougat"
     model: Any
     shapes: tuple[ShapeConfig, ...]
     source: str = ""

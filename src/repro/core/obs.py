@@ -55,10 +55,14 @@ from pathlib import Path
 #: the device for the features; ``route.wait``: the consumer blocked on
 #: the route step); ``prefetch.wait`` is the consumer blocked on the
 #: prefetch queue; ``gc`` and ``compile`` are pauses the plane's hooks
-#: record.
+#: record. The ``parse.*`` spans are a device parser's work inside
+#: ``reparse`` (``core/parser_model``): drawing the page images, the
+#: encoder, the decode, and ``parse.wait``, the host blocked on the
+#: device inside the last two.
 SPAN_STAGES = ("prepare", "prepare.channel", "prepare.features",
                "prepare.wait", "route", "route.wait", "complete",
-               "reparse", "probe", "cache_lookup", "prefetch.wait", "gc",
+               "reparse", "parse.render", "parse.encode", "parse.decode",
+               "parse.wait", "probe", "cache_lookup", "prefetch.wait", "gc",
                "compile", "forward", "reissue", "dedup", "round",
                "scenario", "join", "leave", "admission_rejected")
 
@@ -71,6 +75,13 @@ STAGE_HISTOGRAMS = {"prepare": "engine.prepare_s", "route": "engine.route_s",
 #: counter: batches whose route step ran the encoder attention kernel
 #: (``engine.route_batch``, device path), so a run shows it engaged
 ROUTE_ATTENTION_KERNEL = "route.attention_kernel"
+
+#: counters of a device parser's decode (``core/parser_model``, always
+#: on): pages parsed; decode steps; slots times steps; steps of slots
+#: whose page was still decoding; and the sum, over those live steps,
+#: of the page's cache length (its position + 1)
+PARSE_COUNTERS = ("parse.pages", "parse.decode_steps", "parse.slot_steps",
+                  "parse.live_slot_steps", "parse.live_kv_positions")
 
 #: the profiler-trace name of a span is this prefix and its name
 ANNOTATION_PREFIX = "adaparse."
@@ -101,6 +112,7 @@ _CNAME = {
     # waits and pauses
     "prepare.wait": "thread_state_unknown",
     "route.wait": "thread_state_unknown",
+    "parse.wait": "thread_state_unknown",
     "prefetch.wait": "thread_state_sleeping",
     "gc": "terrible",
     "compile": "bad",

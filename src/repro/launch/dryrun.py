@@ -16,8 +16,7 @@ Two compiles per cell:
    so scanned programs under-report FLOPs/bytes by the trip count. We
    compile fully-unrolled 1-layer and 2-layer variants (layers identical
    => exact linear extrapolation): corrected = c1*(2-L) + c2*(L-1).
-   ViT (enc+dec scans) uses a 3-point plane fit; DIEN extrapolates the
-   GRU trip count. Recorded FLOPs/bytes/collective-bytes are corrected;
+   DIEN extrapolates the GRU trip count. Recorded FLOPs/bytes/collective-bytes are corrected;
    memory numbers always come from the production compile.
 
 This module MUST be the process entry point — the XLA_FLAGS line above
@@ -87,13 +86,6 @@ def costing_plan(arch, shape_name) -> list[tuple[object, float]] | None:
         L = m.n_layers
         mk = lambda n: r(m, n_layers=n, scan_layers=False)
         return [(mk(1), 2.0 - L), (mk(2), L - 1.0)]
-    if arch.family == "vit_parser":
-        Le, Ld = m.enc_layers, m.dec_layers
-        mk = lambda e, d: r(m, enc_layers=e, dec_layers=d, scan_layers=False)
-        if shape_name == "parse_decode":      # encoder not in this cell
-            return [(mk(Le, 1), 2.0 - Ld), (mk(Le, 2), Ld - 1.0)]
-        return [(mk(1, 1), 3.0 - Le - Ld), (mk(2, 1), Le - 1.0),
-                (mk(1, 2), Ld - 1.0)]
     if arch.family == "recsys" and m.kind == "dien":
         T = m.seq_len
         mk = lambda t: r(m, seq_len=t, unroll_gru=True)

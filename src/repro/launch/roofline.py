@@ -198,22 +198,6 @@ def model_flops_for(arch_id: str, shape_name: str) -> float:
         if shape.name.startswith("dpo"):
             mult = 6.0 * 2 + 2.0 * 2          # 2 policy fwd+bwd, 2 ref fwd
         return mult * n * tokens
-    if arch.family == "vit_parser":
-        cfg = arch.model
-        n_enc = cfg.enc_layers * (4 * cfg.enc_d_model ** 2
-                                  + 2 * cfg.enc_d_model * cfg.enc_d_ff)
-        n_dec = cfg.dec_layers * (8 * cfg.dec_d_model ** 2
-                                  + 2 * cfg.dec_d_model * cfg.dec_d_ff)
-        b = shape["global_batch"]
-        t = shape.dims.get("dec_len", 0)
-        mult = 6.0 if shape.kind == "train" else 2.0
-        enc_toks = b * cfg.n_patches
-        dec_toks = b * (t if shape.kind == "train" else 1)
-        if shape.name == "parse_encode":
-            dec_toks = 0
-        if shape.name == "parse_decode":
-            enc_toks = 0              # decode cell runs the decoder only
-        return mult * (n_enc * enc_toks + n_dec * dec_toks)
     if arch.family == "gnn":
         from repro.launch.specs import _gnn_dims
         cfg = arch.model

@@ -9,7 +9,12 @@ weights), then runs the engine over the test split and reports
 Table-1-style metrics + throughput. ``--router-config`` picks the
 router's encoder: ``reduced`` (the default; 2 layers, d=32, cheap on a
 CPU) or ``published`` (``adaparse-router``: 12 layers, d=768, 512
-tokens — the width the chip runs).
+tokens — the width the chip runs). ``--parser-model`` runs the expensive
+parser as the Nougat model on the device (``core/parser_model``),
+``reduced`` (the tiny preset) or ``published`` (``nougat-base``: Swin
+2/2/14/2 on 896x672 pages, 10-layer mBART decoder) in place of the
+``nougat`` channel backend; the records keep the channel's text. It
+runs in this process, so it takes the local runtime only.
 With ``--nodes N > 1`` the corpus is executed by the multi-node
 ``CampaignExecutor`` (real engine per node over batch shards);
 batch-keyed rng streams make the record set identical to ``--nodes 1``.
@@ -110,6 +115,7 @@ from repro.core.backends import DiskResultStore, ResultCache
 from repro.core.campaign import (CampaignController, CampaignExecutor,
                                  ControllerConfig, ExecutorConfig)
 from repro.core.engine import AdaParseEngine, EngineConfig
+from repro.core.parser_model import PARSER_CONFIGS
 from repro.core.quality import QualityProbeConfig
 from repro.core.router import (AdaParseRouter, LinearStage, make_cls1_labels,
                                make_cls2_labels)
@@ -277,6 +283,11 @@ def main(argv=None):
                          "CPU-sized router (default) or the published "
                          "adaparse-router widths (12 layers, d=768, 512 "
                          "tokens)")
+    ap.add_argument("--parser-model", default=None, choices=PARSER_CONFIGS,
+                    help="run the expensive parser as the Nougat model on "
+                         "the device, at its tiny preset (reduced) or at "
+                         "nougat-base's published widths; local runtime "
+                         "only")
     ap.add_argument("--batch-size", type=int, default=256)
     ap.add_argument("--nodes", type=int, default=1)
     ap.add_argument("--workers", type=int, default=0,
@@ -423,6 +434,7 @@ def main(argv=None):
             ("--tuning-dir", args.tuning_dir is not None),
             ("--heartbeat-timeout", args.heartbeat_timeout is not None),
             ("--transport", args.transport is not None),
+            ("--parser-model", args.parser_model is not None),
             ("--metrics-out", args.metrics_out is not None),
             ("--status-interval", args.status_interval != 0.0),
         ) if changed]
@@ -492,6 +504,12 @@ def main(argv=None):
     if args.fabric_workers and args.nodes != 1:
         ap.error(f"--fabric-workers {args.fabric_workers} and --nodes "
                  f"{args.nodes} both set the fleet size; choose one")
+    if args.parser_model is not None and (args.workers
+                                          or args.fabric_workers):
+        ap.error(f"--parser-model {args.parser_model} runs Nougat in this "
+                 f"process, and the process and fabric runtimes build "
+                 f"their engines in worker processes; drop --workers/"
+                 f"--fabric-workers (--nodes N runs the local runtime)")
     if args.router_config != "reduced" and args.variant != "llm":
         ap.error(f"--router-config {args.router_config} picks the CLS-III "
                  f"encoder, which only the llm variant runs; add "
@@ -608,6 +626,14 @@ def main(argv=None):
               else build_llm_router(
                   train, ccfg, rng,
                   enc_cfg=router_encoder_config(args.router_config)))
+    if args.parser_model is not None:
+        import jax
+
+        from repro.core import parser_model
+        parser = parser_model.register(args.parser_model, seed=args.seed)
+        parser.warm()
+        print(f"[serve] expensive parser: {parser.cfg.name} on "
+              f"{jax.devices()[0].device_kind}")
     nodes = (args.workers or args.fabric_workers
              or (len(pools) if pools else args.nodes))
     ecfg = EngineConfig(alpha=args.alpha, batch_size=args.batch_size,

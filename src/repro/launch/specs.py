@@ -19,11 +19,11 @@ import numpy as np
 from repro.common import Param, abstractify, is_param, unwrap
 from repro.configs.base import (ArchConfig, EncoderConfig, GNNConfig,
                                 LMConfig, RecsysConfig, ShapeConfig,
-                                VitParserConfig, get_config)
+                                get_config)
 from repro.distributed.meshrules import AxisRules
 from repro.models import encoder as enc_lib
+from repro.models import nougat as nougat_lib
 from repro.models import transformer as lm_lib
-from repro.models import vit_parser as vp_lib
 from repro.models.attention import KVCache
 from repro.models.gnn import equiformer as eq_lib
 from repro.models.gnn import sampler as sampler_lib
@@ -496,102 +496,51 @@ def _router_cell(arch, shape, rules, abstract, seed=0) -> Cell:
 
 
 def _nougat_cell(arch, shape, rules, abstract, seed=0) -> Cell:
-    cfg: VitParserConfig = arch.model
-    params = vp_lib.init_vit_parser(cfg, seed, abstract=abstract)
-    params_raw = unwrap(params)
+    """Training on page images, or one of the two serving programs,
+    with ``global_batch`` pages (the decode step: slots)."""
     b = shape["global_batch"]
-    patch_dim = cfg.patch * cfg.patch * 3
-    n_p = cfg.n_patches
-    cdt = jnp.dtype(cfg.compute_dtype)
+    cfg = dataclasses.replace(arch.model, decode_slots=b,
+                              encode_chunk=min(b, arch.model.encode_chunk))
 
-    def mk_patches():
-        if abstract:
-            return _sds((b, n_p, patch_dim), cdt)
-        return jnp.asarray(np.random.RandomState(seed).randn(
-            b, n_p, patch_dim), cdt)
+    def make(fn):
+        return jax.eval_shape(fn) if abstract else fn()
 
+    params = make(lambda: nougat_lib.init_params(cfg, seed))
+    images = make(lambda: jnp.asarray(nougat_lib.page_images(
+        [np.arange(10, 10 + 64 * (i + 1)) for i in range(b)], cfg)))
     if shape.kind == "train":
-        t = min(shape["dec_len"], cfg.max_dec_len)
+        t = min(shape["dec_len"], cfg.cache_len)
         opt, kind = _optimizer_for(arch)
-        opt_state = (_abstract_opt_state(opt, params_raw) if abstract
-                     else opt.init(params_raw))
-        if abstract:
-            toks = _sds((b, t), jnp.int32)
-        else:
-            toks = jnp.zeros((b, t), jnp.int32)
-        batch = {"patches": mk_patches(), "tokens": toks, "labels": toks}
+        opt_state = make(lambda: opt.init(params))
+        toks = make(lambda: jnp.zeros((b, t), jnp.int32))
+        batch = {"images": images, "tokens": toks, "labels": toks}
 
-        def train_step(params_raw, opt_state, step, batch):
-            (loss, _), grads = jax.value_and_grad(
-                lambda p: vp_lib.parser_loss(p, cfg, batch),
-                has_aux=True)(params_raw)
-            updates, opt_state = opt.update(grads, opt_state, params_raw,
-                                            step)
-            return apply_updates(params_raw, updates), opt_state, loss
+        def train_step(params, opt_state, step, batch):
+            loss, grads = jax.value_and_grad(
+                lambda p: nougat_lib.loss(p, cfg, batch))(params)
+            updates, opt_state = opt.update(grads, opt_state, params, step)
+            return apply_updates(params, updates), opt_state, loss
 
-        in_sh = None
-        if rules is not None:
-            axes = {"patches": ("pages", "patches", None),
-                    "tokens": ("pages", "seq"), "labels": ("pages", "seq")}
-            in_sh = (rules.param_shardings(params),
-                     _opt_state_shardings(rules, params, kind),
-                     rules.sharding_for((), ()),
-                     _batch_shardings(rules, axes, batch))
         step0 = _sds((), jnp.int32) if abstract else jnp.asarray(0)
         return Cell(arch.arch_id, shape.name, "train", train_step,
-                    (params_raw, opt_state, step0, batch), in_sh,
+                    (params, opt_state, step0, batch),
                     donate_argnums=(0, 1))
-
+    caches = make(lambda: nougat_lib.slot_caches(cfg))
+    slot = _sds((), jnp.int32) if abstract else jnp.asarray(0, jnp.int32)
     if shape.name == "parse_encode":
-        def encode_step(params_raw, patches):
-            memory = vp_lib.encode_pages(params_raw, cfg, patches)
-            state = vp_lib.init_dec_state(params_raw, cfg, memory)
-            return state.xk, state.xv
-
-        patches = mk_patches()
-        in_sh = None
-        if rules is not None:
-            in_sh = (rules.param_shardings(params),
-                     rules.sharding_for(("pages", "patches", None),
-                                        (b, n_p, patch_dim)))
-        return Cell(arch.arch_id, shape.name, "serve", encode_step,
-                    (params_raw, patches), in_sh)
-
-    # parse_decode: one token for the in-flight page batch
-    t = min(shape["dec_len"], cfg.max_dec_len)
-    dh = cfg.dec_d_model // cfg.dec_heads
-
-    def decode_step(params_raw, tok, cache_k, cache_v, xk, xv, pos):
-        state = vp_lib.DecState(KVCache(cache_k, cache_v), xk, xv)
-        logits, state = vp_lib.dec_step(params_raw, cfg, tok, state, pos)
-        return logits, state.cache.k, state.cache.v
-
-    cshape = (cfg.dec_layers, b, t, cfg.dec_heads, dh)
-    xshape = (cfg.dec_layers, b, n_p, cfg.dec_heads, dh)
-    if abstract:
-        tok = _sds((b, 1), jnp.int32)
-        ck = cv = _sds(cshape, cdt)
-        xk = xv = _sds(xshape, cdt)
-        pos = _sds((), jnp.int32)
-    else:
-        tok = jnp.zeros((b, 1), jnp.int32)
-        ck = cv = jnp.zeros(cshape, cdt)
-        xk = xv = jnp.zeros(xshape, cdt)
-        pos = jnp.asarray(t - 1)
-    in_sh = None
-    if rules is not None:
-        c_ax = ("layers", "pages", "kv_seq", "heads", "d_head")
-        x_ax = ("layers", "pages", "patches", "heads", "d_head")
-        in_sh = (rules.param_shardings(params),
-                 rules.sharding_for(("pages", None), (b, 1)),
-                 rules.sharding_for(c_ax, cshape),
-                 rules.sharding_for(c_ax, cshape),
-                 rules.sharding_for(x_ax, xshape),
-                 rules.sharding_for(x_ax, xshape),
-                 rules.sharding_for((), ()))
-    return Cell(arch.arch_id, shape.name, "decode", decode_step,
-                (params_raw, tok, ck, cv, xk, xv, pos), in_sh,
-                donate_argnums=(2, 3))
+        return Cell(arch.arch_id, shape.name, "serve",
+                    lambda p, im, xk, xv, at: nougat_lib.encode_into_slots(
+                        p, cfg, im, xk, xv, at),
+                    (params, images, caches["xk"], caches["xv"], slot),
+                    donate_argnums=(2, 3))
+    state = make(lambda: nougat_lib.decode_state(
+        cfg, nougat_lib.slot_caches(cfg), [min(16, cfg.cache_len)] * b,
+        [0], [0]))
+    return Cell(arch.arch_id, shape.name, "decode",
+                lambda p, st, xk, xv, t: nougat_lib.decode_step(
+                    p, cfg, st, xk, xv, t, cfg.cache_len),
+                (params, state, caches["xk"], caches["xv"], slot),
+                donate_argnums=(1,))
 
 
 # ---------------------------------------------------------------------------
@@ -626,7 +575,7 @@ def build_cell(arch_id: str, shape_name: str, rules: AxisRules | None = None,
         return _recsys_cell(arch, shape, rules, abstract, seed)
     if fam == "encoder":
         return _router_cell(arch, shape, rules, abstract, seed)
-    if fam == "vit_parser":
+    if fam == "nougat":
         return _nougat_cell(arch, shape, rules, abstract, seed)
     raise ValueError(fam)
 
@@ -634,7 +583,7 @@ def build_cell(arch_id: str, shape_name: str, rules: AxisRules | None = None,
 def _reduce_shape(family: str, shape: ShapeConfig) -> ShapeConfig:
     """Shrink a workload cell for CPU smoke tests (same kind/topology)."""
     d = dict(shape.dims)
-    if family in ("lm", "encoder", "vit_parser"):
+    if family in ("lm", "encoder", "nougat"):
         if "seq_len" in d:
             d["seq_len"] = min(d["seq_len"], 64)
         if "global_batch" in d:

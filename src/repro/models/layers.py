@@ -140,8 +140,10 @@ def swiglu(gate: jax.Array, up: jax.Array) -> jax.Array:
     return jax.nn.silu(gate) * up
 
 
-def gelu(x: jax.Array) -> jax.Array:
-    return jax.nn.gelu(x, approximate=True)
+def gelu(x: jax.Array, approximate: bool = True) -> jax.Array:
+    """GELU: the tanh approximation (the router's), or with
+    ``approximate=False`` the erf form (Swin's and mBART's)."""
+    return jax.nn.gelu(x, approximate=approximate)
 
 
 # ---------------------------------------------------------------------------

@@ -203,3 +203,68 @@ def test_published_route_step_compiles_with_kernel(one_chip,
     assert not re.search(r" (copy|transpose)\(", ops), user
     scores = b * cfg.n_heads * cfg.max_len ** 2 * 4
     assert compiled.memory_analysis().temp_size_in_bytes < scores / 2
+
+
+def _nougat_program(which: str, one_chip, span: int | None = None):
+    """``parse_encode`` (a chunk of pages into the slot caches) or, with
+    ``span``, ``parse_decode`` (a greedy step reading ``span`` cache
+    positions) at nougat-base's published widths, compiled for the
+    described chip."""
+    from repro.core.parser_model import parser_config
+    from repro.models import nougat
+
+    cfg = parser_config("published")
+    on_chip = lambda t: jax.tree_util.tree_map(          # noqa: E731
+        lambda a: _sds(a.shape, a.dtype, one_chip), t)
+    params = on_chip(jax.eval_shape(lambda: nougat.init_params(cfg, 0)))
+    caches = on_chip(jax.eval_shape(lambda: nougat.slot_caches(cfg)))
+    encode, decode = nougat.serving_programs(cfg)
+    step = _sds((), jnp.int32, one_chip)
+    if span is None:
+        images = _sds((cfg.encode_chunk,) + cfg.image_hw, jnp.uint8,
+                      one_chip)
+        return cfg, encode.lower(params, images, caches["xk"], caches["xv"],
+                                 step).compile()
+    state = on_chip(jax.eval_shape(lambda: nougat.decode_state(
+        cfg, nougat.slot_caches(cfg), [0] * cfg.decode_slots, [0] * 16,
+        [-1] * 16)))
+    return cfg, decode.lower(params, state, caches["xk"], caches["xv"],
+                             step, span=span).compile()
+
+
+def test_nougat_encode_compiles_at_published_widths(one_chip,
+                                                    no_compile_cache):
+    """nougat-base's encoder (896x672 pages, Swin 2/2/14/2) on a chunk of
+    8 pages into the 96 slots' cross-attention K/V, which it updates in
+    place: the caches' 2.3 GB are aliased, not copied."""
+    cfg, compiled = _nougat_program("encode", one_chip)
+    assert (cfg.image_hw, cfg.depths, cfg.enc_tokens) == \
+        ((896, 672), (2, 2, 14, 2), 588)
+    mem = compiled.memory_analysis()
+    cross = 2 * cfg.dec_layers * cfg.decode_slots * cfg.enc_tokens \
+        * cfg.dec_d_model * 2
+    assert mem.alias_size_in_bytes >= cross
+    print(f"parse_encode: temp_size_in_bytes {mem.temp_size_in_bytes}")
+    assert mem.temp_size_in_bytes < 4 * 1024 ** 3
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) < HBM_BYTES
+
+
+@pytest.mark.parametrize("span", [128, 896])
+def test_nougat_decode_compiles_at_published_widths(one_chip,
+                                                    no_compile_cache, span):
+    """A decode step of nougat-base (10 layers, d 1024, 50,000 tokens)
+    over 96 slots: the self-attention cache is updated in place and
+    read where it lies, up to ``span``: the temporaries stay far below
+    one copy of the cache (3.5 GB), and the cross-attention K/V (2.3
+    GB) is read, not copied."""
+    cfg, compiled = _nougat_program("decode", one_chip, span)
+    mem = compiled.memory_analysis()
+    own = 2 * cfg.dec_layers * cfg.decode_slots * cfg.cache_len \
+        * cfg.dec_d_model * 2
+    assert mem.alias_size_in_bytes >= own
+    print(f"parse_decode span {span}: temp_size_in_bytes "
+          f"{mem.temp_size_in_bytes}")
+    assert mem.temp_size_in_bytes < own / 4
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes) < HBM_BYTES
