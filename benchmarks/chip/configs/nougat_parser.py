@@ -36,7 +36,12 @@ reference teacher-forced on the same tokens:
 - ``parse_logit_gap``: the largest |program - reference| logit over the
   reference's logit standard deviation at that step;
 - ``parse_token_regret``: the reference's best logit less its logit of
-  the program's token, on the same scale;
+  the program's token, on the same scale (a reading, under no limit: a
+  sound bf16 decode may take a token whose reference logit is a near
+  tie, so its readings overlap the control's);
+- ``parse_fed_diff`` (exact): kept steps whose next token, the one the
+  decode fed on, is not the argmax of the logits the decode produced
+  there;
 - ``parse_page_diff`` (exact): pages of the selected documents not
   decoded, and pages decoded that are not theirs;
 - ``parse_step_diff`` (exact): pages decoded for another number of
@@ -55,7 +60,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-EXACT = ("parse_page_diff", "parse_step_diff")
+EXACT = ("parse_fed_diff", "parse_page_diff", "parse_step_diff")
 MEAN = (0.485, 0.456, 0.406)
 STD = (0.229, 0.224, 0.225)
 MASKED = -100.0
@@ -354,13 +359,16 @@ def readings(sample: dict, weights, widths: dict) -> dict:
         page_diff = len(set(keys) ^ set(pages))
         step_diff = sum(k not in pages or len(pages[k]) != s
                         for k, s in zip(keys, got["steps"]))
+        fed_diff = sum(int(np.sum(np.argmax(c["logits"], -1)
+                                  != c["tokens"][np.asarray(c["steps"]) + 1]))
+                       for c in got["captures"])
         # (page, steps, fed tokens, the program's token at each step,
         # its logits there)
         caps = [(tuple(c["page"]), c["steps"], c["tokens"][:-1],
                  c["tokens"][np.asarray(c["steps"]) + 1], c["logits"])
                 for c in got["captures"]]
     else:
-        page_diff = step_diff = 0
+        page_diff = step_diff = fed_diff = 0
         keys = list(pages)
         rng = np.random.RandomState(int(sample["key"]) % 2 ** 32)
         caps = []
@@ -382,4 +390,5 @@ def readings(sample: dict, weights, widths: dict) -> dict:
         chosen = np.take_along_axis(ref, np.asarray(tok)[:, None], -1)[:, 0]
         regret = max(regret, float(np.max((ref.max(-1) - chosen) / scale)))
     return {"parse_logit_gap": gap, "parse_token_regret": regret,
-            "parse_page_diff": page_diff, "parse_step_diff": step_diff}
+            "parse_fed_diff": fed_diff, "parse_page_diff": page_diff,
+            "parse_step_diff": step_diff}

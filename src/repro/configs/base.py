@@ -120,7 +120,7 @@ class NougatConfig:
     decoder (arXiv:2001.08210) that cross-attends to the encoder's last
     stage. The serving fields size the decode: ``decode_slots`` pages
     decode side by side, each over a self-attention cache of
-    ``cache_len`` positions read in blocks of ``kv_block``."""
+    ``cache_len`` positions."""
 
     name: str
     # encoder (Swin)
@@ -147,8 +147,6 @@ class NougatConfig:
     # serving
     decode_slots: int = 96
     cache_len: int = 896
-    kv_block: int = 128
-    encode_chunk: int = 8
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
 

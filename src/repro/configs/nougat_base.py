@@ -28,15 +28,15 @@ def tiny() -> NougatConfig:
     """Every mechanism of the published model at a CPU size: two stages
     with a merge, unshifted and shifted blocks with the wrap mask on a
     grid larger than the window, the relative bias, two decoder
-    layers, and a cache read in several blocks (pages of up to 384
-    tokens, as ``serve``'s corpus has)."""
+    layers, a cache read in several blocks (pages of up to 384
+    tokens, as ``serve``'s corpus has) and slots for several encode
+    chunks."""
     return NougatConfig(
         name="nougat-tiny", image_hw=(64, 96), patch=4, embed_dim=16,
         depths=(2, 2), heads=(2, 4), window=4, dec_layers=2,
         dec_d_model=32, dec_heads=4, dec_d_ff=64, vocab_size=96,
-        max_positions=512, glyph_cell=(8, 12), decode_slots=8,
-        cache_len=384, kv_block=64, encode_chunk=4,
-        param_dtype="float32", compute_dtype="float32")
+        max_positions=512, glyph_cell=(8, 12), decode_slots=16,
+        cache_len=384, param_dtype="float32", compute_dtype="float32")
 
 
 @register("nougat-base")

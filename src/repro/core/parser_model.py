@@ -11,15 +11,15 @@ One ``parse_batch``:
 2. Every page of every selected document is drawn as an image
    (``models.nougat.page_images``; span ``parse.render``).
 3. In waves of ``decode_slots`` pages (one wave where the batch's pages
-   fit): the encoder runs over every slot, in chunks of ``encode_chunk``
-   pages, a blank page in each slot no page holds, and writes each
-   slot's cross-attention K/V (``parse.encode``); then every slot
-   decodes greedily from BOS at once, each page for exactly its own
-   token count, the cache read a ``kv_block`` at a time
-   (``parse.decode``). Both end blocked on the device (``parse.wait``).
-   So a wave's device time does not depend on how many pages it holds:
-   it lasts as long as its longest page, and slots whose page has
-   finished, or that hold none, still run.
+   fit): the encoder runs over the wave's pages in chunks of
+   ``nougat.ENCODE_CHUNK`` (the last filled out with blank pages) and
+   writes each page's cross-attention K/V into its slot
+   (``parse.encode``); then every slot decodes greedily from BOS at
+   once, each page for exactly its own token count, the cache read a
+   ``nougat.KV_BLOCK`` at a time (``parse.decode``). Both end blocked
+   on the device (``parse.wait``). The decode lasts as long as the
+   wave's longest page: slots whose page has finished, or that hold
+   none, still step.
 4. ``last`` keeps what the batch decoded, for a check: each page's
    decoded step count, and at seeded (page, step) pairs (2 pages, 8
    steps each, among them each page's first and last) the logits the
@@ -60,12 +60,13 @@ class NougatBackend:
     name (and the text) of the channel backend it stands in for."""
 
     def __init__(self, cfg, params, name: str = "nougat"):
-        if cfg.decode_slots % cfg.encode_chunk:
+        if cfg.decode_slots % nougat.ENCODE_CHUNK:
             raise ValueError(f"{cfg.decode_slots} decode slots do not hold "
-                             f"whole encode chunks of {cfg.encode_chunk}")
-        if cfg.cache_len % cfg.kv_block or cfg.cache_len > cfg.max_positions:
+                             f"whole encode chunks of {nougat.ENCODE_CHUNK}")
+        if (cfg.cache_len % nougat.KV_BLOCK
+                or cfg.cache_len > cfg.max_positions):
             raise ValueError(f"cache of {cfg.cache_len} positions is not "
-                             f"whole blocks of {cfg.kv_block} within the "
+                             f"whole blocks of {nougat.KV_BLOCK} within the "
                              f"{cfg.max_positions} learned positions")
         self.cfg, self.params = cfg, params
         self.channel = backends.get_backend(name)
@@ -119,11 +120,11 @@ class NougatBackend:
 
     def _wave(self, pages: list, lengths: np.ndarray, caps: list):
         cfg = self.cfg
-        slots, chunk = cfg.decode_slots, cfg.encode_chunk
+        slots, chunk = cfg.decode_slots, nougat.ENCODE_CHUNK
         with obs.span("parse.render", None):
             images = nougat.page_images(pages, cfg)
             images = np.concatenate([images, np.full(
-                (slots - len(images),) + images.shape[1:], 255, np.uint8)])
+                (-len(images) % chunk,) + images.shape[1:], 255, np.uint8)])
         c = self.caches
         with obs.span("parse.encode", None):
             for i in range(0, len(images), chunk):
@@ -139,7 +140,7 @@ class NougatBackend:
             state = self._state(n, caps)
             for t in range(t_max):
                 state = self.decode(self.params, state, c["xk"], c["xv"],
-                                    np.int32(t), span=_span(t, cfg.kv_block))
+                                    np.int32(t), span=_span(t))
             c["ck"], c["cv"] = state["ck"], state["cv"]
             with obs.span("parse.wait", None):
                 steps, tokens, logits = jax.device_get(
@@ -167,14 +168,14 @@ class NougatBackend:
         program, with no step run."""
         cfg = self.cfg
         c = self.caches
-        blank = np.full((cfg.encode_chunk,) + tuple(cfg.image_hw), 255,
+        blank = np.full((nougat.ENCODE_CHUNK,) + tuple(cfg.image_hw), 255,
                         np.uint8)
         c["xk"], c["xv"] = self.encode(self.params, blank, c["xk"], c["xv"],
                                        np.int32(0))
         state = self._state(np.zeros(cfg.decode_slots, np.int32), [])
-        for t in range(0, cfg.cache_len, cfg.kv_block):
+        for t in range(0, cfg.cache_len, nougat.KV_BLOCK):
             state = self.decode(self.params, state, c["xk"], c["xv"],
-                                np.int32(t), span=_span(t, cfg.kv_block))
+                                np.int32(t), span=_span(t))
         c["ck"], c["cv"] = jax.block_until_ready((state["ck"], state["cv"]))
 
 
@@ -195,9 +196,9 @@ def capture_pairs(rng, lengths: np.ndarray) -> list:
     return out
 
 
-def _span(t: int, block: int) -> int:
+def _span(t: int) -> int:
     """Cache positions a step at position ``t`` reads: whole blocks."""
-    return (t // block + 1) * block
+    return (t // nougat.KV_BLOCK + 1) * nougat.KV_BLOCK
 
 
 def _split(logits: np.ndarray, caps: list) -> list:

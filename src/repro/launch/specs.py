@@ -499,8 +499,7 @@ def _nougat_cell(arch, shape, rules, abstract, seed=0) -> Cell:
     """Training on page images, or one of the two serving programs,
     with ``global_batch`` pages (the decode step: slots)."""
     b = shape["global_batch"]
-    cfg = dataclasses.replace(arch.model, decode_slots=b,
-                              encode_chunk=min(b, arch.model.encode_chunk))
+    cfg = dataclasses.replace(arch.model, decode_slots=b)
 
     def make(fn):
         return jax.eval_shape(fn) if abstract else fn()

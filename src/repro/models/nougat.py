@@ -26,7 +26,7 @@ Serving splits the decoder in two programs, named so that a profiler
 trace shows them: ``parse_encode`` (a chunk of pages through the
 encoder into every layer's cross-attention K/V, written into page
 slots) and ``parse_decode`` (one greedy step for every slot at once
-over a self-attention cache kept in blocks of ``kv_block`` positions;
+over a self-attention cache kept in blocks of ``KV_BLOCK`` positions;
 one program per block count, so a step reads the cache only up to the
 block its position lies in). ``core/parser_model`` drives them.
 
@@ -64,6 +64,10 @@ PIXEL_STD = (0.229, 0.224, 0.225)
 #: Swin's additive mask between the wrapped regions of a shifted window
 MASKED = -100.0
 NEG_INF = -1e30
+#: serving: cache positions per self-attention block (one decode program
+#: per block count) and pages per encoder call
+KV_BLOCK = 128
+ENCODE_CHUNK = 8
 
 
 # ---------------------------------------------------------------------------
@@ -422,14 +426,14 @@ def slot_caches(cfg: NougatConfig):
     """Zeroed page-slot caches, one row of d (every head's head_dim side
     by side) per position, one array per decoder layer: cross-attention
     ``xk``/``xv`` (slots, enc_tokens, d), and self-attention ``ck``/``cv``
-    split further into blocks of ``kv_block`` positions, (slots,
-    kv_block, d) each, so a step reads whole arrays and writes one."""
+    split further into blocks of ``KV_BLOCK`` positions, (slots,
+    KV_BLOCK, d) each, so a step reads whole arrays and writes one."""
     cdt = jnp.dtype(cfg.compute_dtype)
     s, d, L = cfg.decode_slots, cfg.dec_d_model, cfg.dec_layers
-    blocks = cfg.cache_len // cfg.kv_block
+    blocks = cfg.cache_len // KV_BLOCK
 
     def own():
-        return [[jnp.zeros((s, cfg.kv_block, d), cdt) for _ in range(blocks)]
+        return [[jnp.zeros((s, KV_BLOCK, d), cdt) for _ in range(blocks)]
                 for _ in range(L)]
     return {"xk": [jnp.zeros((s, cfg.enc_tokens, d), cdt) for _ in range(L)],
             "xv": [jnp.zeros((s, cfg.enc_tokens, d), cdt) for _ in range(L)],
@@ -481,9 +485,9 @@ def decode_step(params, cfg: NougatConfig, state: dict, xk, xv, t,
     dec = params["dec"]
     lay = dec["layers"]
     h = cfg.dec_heads
-    blocks = span // cfg.kv_block
+    blocks = span // KV_BLOCK
     last = blocks - 1
-    at = (0, t - last * cfg.kv_block, 0)
+    at = (0, t - last * KV_BLOCK, 0)
     mask = jnp.where(jnp.arange(span) <= t, 0.0, NEG_INF)
     x = _embed(dec, cfg, state["tok"], t)
     new = {"ck": [], "cv": []}

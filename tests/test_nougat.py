@@ -60,15 +60,16 @@ def test_reference_init_matches_the_program_layout(tiny):
 
 
 @pytest.mark.parametrize("lengths", [(20, 37, 5, 64, 130, 1),
-                                     tuple(range(40, 50))],
+                                     tuple(range(40, 60))],
                          ids=["one_wave", "two_waves"])
 def test_decode_through_the_cache_matches_the_reference(tiny, ref,
                                                         monkeypatch,
                                                         lengths):
-    """Every step of two pages per wave (all their steps captured), the
-    decode's logits against the reference's full forward pass
-    teacher-forced on the tokens the decode fed; each page decoded for
-    exactly its token count."""
+    """Every step of two pages (all their steps captured), the decode's
+    logits against the reference's full forward pass teacher-forced on
+    the tokens the decode fed; each page decoded for exactly its token
+    count. The first case takes one encode chunk and two cache blocks;
+    the second two waves, the first of two chunks."""
     cfg, widths, weights = tiny
     monkeypatch.setattr(PM, "CAPTURE_STEPS", 200)
     be = PM.NougatBackend(cfg, weights)
@@ -121,6 +122,7 @@ def test_control_reads_far_above_the_program(tiny, ref):
                       "key": 11}, weights, widths)
     assert r["parse_logit_gap"] > 100 * TOLERANCE
     assert r["parse_page_diff"] == r["parse_step_diff"] == 0
+    assert r["parse_fed_diff"] == 0
 
 
 @pytest.mark.parametrize("h,w,window", [(16, 24, 4), (8, 12, 4),

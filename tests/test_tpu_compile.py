@@ -221,7 +221,7 @@ def _nougat_program(which: str, one_chip, span: int | None = None):
     encode, decode = nougat.serving_programs(cfg)
     step = _sds((), jnp.int32, one_chip)
     if span is None:
-        images = _sds((cfg.encode_chunk,) + cfg.image_hw, jnp.uint8,
+        images = _sds((nougat.ENCODE_CHUNK,) + cfg.image_hw, jnp.uint8,
                       one_chip)
         return cfg, encode.lower(params, images, caches["xk"], caches["xv"],
                                  step).compile()
