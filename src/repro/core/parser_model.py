@@ -11,15 +11,16 @@ One ``parse_batch``:
 2. Every page of every selected document is drawn as an image
    (``models.nougat.page_images``; span ``parse.render``).
 3. In waves of ``decode_slots`` pages (one wave where the batch's pages
-   fit): the encoder runs over the wave's pages in chunks of
-   ``nougat.ENCODE_CHUNK`` (the last filled out with blank pages) and
-   writes each page's cross-attention K/V into its slot
+   fit): the encoder runs over every slot, in chunks of
+   ``nougat.ENCODE_CHUNK`` pages, a blank page in each slot no page
+   holds, and writes each slot's cross-attention K/V
    (``parse.encode``); then every slot decodes greedily from BOS at
    once, each page for exactly its own token count, the cache read a
    ``nougat.KV_BLOCK`` at a time (``parse.decode``). Both end blocked
-   on the device (``parse.wait``). The decode lasts as long as the
-   wave's longest page: slots whose page has finished, or that hold
-   none, still step.
+   on the device (``parse.wait``). So a wave's device time does not
+   depend on how many pages it holds: it lasts as long as its longest
+   page, and slots whose page has finished, or that hold none, still
+   run.
 4. ``last`` keeps what the batch decoded, for a check: each page's
    decoded step count, and at seeded (page, step) pairs (2 pages, 8
    steps each, among them each page's first and last) the logits the
@@ -124,7 +125,7 @@ class NougatBackend:
         with obs.span("parse.render", None):
             images = nougat.page_images(pages, cfg)
             images = np.concatenate([images, np.full(
-                (-len(images) % chunk,) + images.shape[1:], 255, np.uint8)])
+                (slots - len(images),) + images.shape[1:], 255, np.uint8)])
         c = self.caches
         with obs.span("parse.encode", None):
             for i in range(0, len(images), chunk):

@@ -68,8 +68,9 @@ def test_decode_through_the_cache_matches_the_reference(tiny, ref,
     """Every step of two pages (all their steps captured), the decode's
     logits against the reference's full forward pass teacher-forced on
     the tokens the decode fed; each page decoded for exactly its token
-    count. The first case takes one encode chunk and two cache blocks;
-    the second two waves, the first of two chunks."""
+    count. Every wave encodes both chunks of the 16 slots, blank pages
+    in the slots it leaves empty; the first case reads two cache
+    blocks, the second takes two waves."""
     cfg, widths, weights = tiny
     monkeypatch.setattr(PM, "CAPTURE_STEPS", 200)
     be = PM.NougatBackend(cfg, weights)
@@ -89,6 +90,42 @@ def test_decode_through_the_cache_matches_the_reference(tiny, ref,
         np.testing.assert_array_equal(cap["tokens"][1:],
                                       np.argmax(cap["logits"], -1))
         assert cap["tokens"][0] == cfg.bos_id
+
+
+def test_every_wave_encodes_every_slot(tiny):
+    """A wave runs the encoder over all its slots whatever the number of
+    pages it holds, and a slot no page holds gets a blank page's
+    cross-attention K/V, not what the wave before left there."""
+    cfg, _, weights = tiny
+    be = PM.NougatBackend(cfg, weights)
+    calls = []
+    encode = be.encode
+
+    def counted(*args):
+        calls.append(args[1].shape[0])
+        return encode(*args)
+    be.encode = counted
+    full = _pages(5, [12] * cfg.decode_slots)
+    be.run([(0, j) for j in range(len(full))], full,
+           np.random.RandomState(0))
+    assert sum(calls) == cfg.decode_slots
+    calls.clear()
+    be.run([(1, 0)], _pages(6, [9]), np.random.RandomState(0))
+    assert calls == [nougat.ENCODE_CHUNK] * (cfg.decode_slots
+                                             // nougat.ENCODE_CHUNK)
+    # float32 both ways; a chunk of eight and a lone page may round
+    # apart in the last bits, a stale slot differs at order one
+    blank = np.full((1,) + tuple(cfg.image_hw), 255, np.uint8)
+    k, v = nougat.cross_kv(weights, cfg, nougat.encode(weights, cfg, blank))
+    for layer in range(cfg.dec_layers):
+        np.testing.assert_allclose(np.asarray(be.caches["xk"][layer][1:]),
+                                   np.broadcast_to(
+                                       np.asarray(k[layer]),
+                                       be.caches["xk"][layer][1:].shape),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(be.caches["xv"][layer][-1]),
+                                   np.asarray(v[layer][0]),
+                                   rtol=1e-5, atol=1e-5)
 
 
 def test_teacher_forced_forward_matches_the_reference(tiny, ref):
