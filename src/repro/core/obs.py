@@ -83,6 +83,11 @@ ROUTE_ATTENTION_KERNEL = "route.attention_kernel"
 PARSE_COUNTERS = ("parse.pages", "parse.decode_steps", "parse.slot_steps",
                   "parse.live_slot_steps", "parse.live_kv_positions")
 
+#: counter: decode steps of a device parser whose attention ran the
+#: decode attention kernel (``core/parser_model``, always on), so a run
+#: shows it engaged; it equals ``parse.decode_steps`` then
+PARSE_ATTENTION_KERNEL = "parse.attention_kernel"
+
 #: the profiler-trace name of a span is this prefix and its name
 ANNOTATION_PREFIX = "adaparse."
 

@@ -27,7 +27,10 @@ One ``parse_batch``:
    decode produced and the tokens it fed.
 
 Counters (``obs.PARSE_COUNTERS``) count the decode's pages, steps, slot
-steps, live slot steps and live cache positions.
+steps, live slot steps and live cache positions;
+``obs.PARSE_ATTENTION_KERNEL`` the steps whose attention ran
+``kernels.decode_attention``, which reads live slots' K/V only (a TPU,
+or ``force_kernel``).
 """
 from __future__ import annotations
 
@@ -60,7 +63,8 @@ class NougatBackend:
     """``ParserBackend`` running Nougat on the default device, under the
     name (and the text) of the channel backend it stands in for."""
 
-    def __init__(self, cfg, params, name: str = "nougat"):
+    def __init__(self, cfg, params, name: str = "nougat", *,
+                 force_kernel: bool = False):
         if cfg.decode_slots % nougat.ENCODE_CHUNK:
             raise ValueError(f"{cfg.decode_slots} decode slots do not hold "
                              f"whole encode chunks of {nougat.ENCODE_CHUNK}")
@@ -72,7 +76,10 @@ class NougatBackend:
         self.cfg, self.params = cfg, params
         self.channel = backends.get_backend(name)
         self.info = dataclasses.replace(self.channel.info)
-        self.encode, self.decode = nougat.serving_programs(cfg)
+        self.encode, self.decode = nougat.serving_programs(cfg,
+                                                           force_kernel)
+        self.attention_kernel = nougat.uses_attention_kernel(cfg,
+                                                             force_kernel)
         self.caches = nougat.slot_caches(cfg)
         self.last: dict | None = None
 
@@ -150,6 +157,8 @@ class NougatBackend:
                   int((lengths * (lengths + 1) // 2).sum()))
         for name, n in zip(obs.PARSE_COUNTERS, counts):
             obs.metrics().count(name, n)
+        if self.attention_kernel:
+            obs.metrics().count(obs.PARSE_ATTENTION_KERNEL, t_max)
         return steps, tokens, logits
 
     def _state(self, n: np.ndarray, caps: list) -> dict:

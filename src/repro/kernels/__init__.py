@@ -3,6 +3,8 @@
 - flash_attention : Nougat/LM attention (the ViT inference hot loop)
 - encoder_attention: the router encoder's bidirectional attention in
                     the route step (a whole row of keys in VMEM)
+- decode_attention : Nougat's greedy decode step, one query row per page
+                    slot, reading the K/V of live slots only
 - budget_route    : AdaParse's fused alpha-budget select+compact dispatch
 - ngram_score     : fused n-gram BLEU (the quality probe's scorer)
 - fast_features   : fused prepare stage (CLS-I features + LLM tokens)
@@ -17,6 +19,6 @@ where a block size is worth sweeping — autotune.py on the shared
 Every kernel takes ``interpret`` with no default: the ops pass
 ``interpret=(backend != "tpu")``, CPU tests pass ``interpret=True``, and
 tests/test_tpu_compile.py compiles the main-path kernels
-(budget_route, encoder_attention, fast_features, ngram_score) for a
-described v5e chip.
+(budget_route, encoder_attention, decode_attention, fast_features,
+ngram_score) for a described v5e chip.
 """

@@ -28,7 +28,10 @@ encoder into every layer's cross-attention K/V, written into page
 slots) and ``parse_decode`` (one greedy step for every slot at once
 over a self-attention cache kept in blocks of ``KV_BLOCK`` positions;
 one program per block count, so a step reads the cache only up to the
-block its position lies in). ``core/parser_model`` drives them.
+block its position lies in). On a TPU the step's attention is
+``kernels.decode_attention``, which reads the K/V of live slots only
+(rows of whole 128-lane tiles; ``_attend_rows`` otherwise).
+``core/parser_model`` drives them.
 
 Weights (``init_params``) are plain nested dicts; linear weights are
 (in, out):
@@ -56,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import NougatConfig
+from repro.kernels import decode_attention
 from repro.models.layers import gelu, layer_norm
 
 #: Nougat's input normalisation (ImageNet's per-channel mean and std)
@@ -68,6 +72,11 @@ NEG_INF = -1e30
 #: per block count) and pages per encoder call
 KV_BLOCK = 128
 ENCODE_CHUNK = 8
+#: cross-attention K/V rows are kept in whole (16, 128) bf16 tiles: a
+#: (slots, 588, d) cache is laid out position by position (588 rows are
+#: not whole tiles), which interleaves the slots; padded, slot by slot,
+#: so a slot's K/V is one contiguous read. The padding rows are masked.
+CROSS_ROWS = 16
 
 
 # ---------------------------------------------------------------------------
@@ -422,12 +431,19 @@ def loss(params, cfg: NougatConfig, batch):
 # ---------------------------------------------------------------------------
 
 
+def cross_rows(cfg: NougatConfig) -> int:
+    """Rows of the cross-attention caches: ``enc_tokens`` rounded up to
+    whole ``CROSS_ROWS``."""
+    return -(-cfg.enc_tokens // CROSS_ROWS) * CROSS_ROWS
+
+
 def slot_caches(cfg: NougatConfig):
     """Zeroed page-slot caches, one row of d (every head's head_dim side
     by side) per position, one array per decoder layer: cross-attention
-    ``xk``/``xv`` (slots, enc_tokens, d), and self-attention ``ck``/``cv``
-    split further into blocks of ``KV_BLOCK`` positions, (slots,
-    KV_BLOCK, d) each, so a step reads whole arrays and writes one."""
+    ``xk``/``xv`` (slots, ``cross_rows``, d), the first ``enc_tokens``
+    rows a page's, and self-attention ``ck``/``cv`` split further into
+    blocks of ``KV_BLOCK`` positions, (slots, KV_BLOCK, d) each, so a
+    step reads whole arrays and writes one."""
     cdt = jnp.dtype(cfg.compute_dtype)
     s, d, L = cfg.decode_slots, cfg.dec_d_model, cfg.dec_layers
     blocks = cfg.cache_len // KV_BLOCK
@@ -435,9 +451,10 @@ def slot_caches(cfg: NougatConfig):
     def own():
         return [[jnp.zeros((s, KV_BLOCK, d), cdt) for _ in range(blocks)]
                 for _ in range(L)]
-    return {"xk": [jnp.zeros((s, cfg.enc_tokens, d), cdt) for _ in range(L)],
-            "xv": [jnp.zeros((s, cfg.enc_tokens, d), cdt) for _ in range(L)],
-            "ck": own(), "cv": own()}
+
+    def cross():
+        return [jnp.zeros((s, cross_rows(cfg), d), cdt) for _ in range(L)]
+    return {"xk": cross(), "xv": cross(), "ck": own(), "cv": own()}
 
 
 def decode_state(cfg: NougatConfig, caches: dict, n, cap_slot, cap_step):
@@ -468,15 +485,25 @@ def encode_into_slots(params, cfg: NougatConfig, images, xk, xv, slot):
              for a, b in zip(xv, v)])
 
 
+def uses_attention_kernel(cfg: NougatConfig,
+                          force_kernel: bool = False) -> bool:
+    """Whether ``decode_step`` attends through
+    ``kernels.decode_attention`` (its dispatch rule, at the decoder's
+    row width)."""
+    return decode_attention.uses_kernel(cfg.dec_d_model, force_kernel)
+
+
 def decode_step(params, cfg: NougatConfig, state: dict, xk, xv, t,
-                span: int):
+                span: int, force_kernel: bool = False):
     """One greedy step, at position t < ``span``, for every slot at once,
     reading the self-attention cache's first ``span`` positions (whole
     blocks). It embeds ``state["tok"]`` at t, writes its keys and values
     at t, and takes the argmax of its logits as the next token:
     ``tokens[:, t + 1]``. A slot is live while t < ``n``; ``steps``
     counts its live steps. Logits of slot ``cap_slot[j]``, at step
-    ``cap_step[j]``, land in ``cap[j]``.
+    ``cap_step[j]``, land in ``cap[j]``. Through the attention kernel
+    (``uses_attention_kernel``) a slot that is not live reads no K/V
+    and attends to nothing; its token is never read.
 
     ``state``: ``ck``, ``cv`` (the self-attention cache, as
     ``slot_caches``), ``tok`` (slots,), ``tokens`` (slots,
@@ -488,7 +515,16 @@ def decode_step(params, cfg: NougatConfig, state: dict, xk, xv, t,
     blocks = span // KV_BLOCK
     last = blocks - 1
     at = (0, t - last * KV_BLOCK, 0)
-    mask = jnp.where(jnp.arange(span) <= t, 0.0, NEG_INF)
+    if uses_attention_kernel(cfg, force_kernel):
+        live = decode_attention.live_slots(t < state["n"])
+
+        def attend(q, ks, vs, end):
+            return decode_attention.decode_attention(q, ks, vs, live, end)
+    else:
+        def attend(q, ks, vs, end):
+            rows = jnp.arange(sum(k.shape[1] for k in ks))
+            return _attend_rows(q, ks, vs,
+                                jnp.where(rows <= end, 0.0, NEG_INF))
     x = _embed(dec, cfg, state["tok"], t)
     new = {"ck": [], "cv": []}
     for l in range(cfg.dec_layers):
@@ -500,12 +536,12 @@ def decode_step(params, cfg: NougatConfig, state: dict, xk, xv, t,
             own[last] = jax.lax.dynamic_update_slice(
                 own[last], row[:, None].astype(own[last].dtype), at)
             new[name].append(own)
-        o = _attend_rows(_query(y, lp, "sa", h), new["ck"][l][:blocks],
-                         new["cv"][l][:blocks], mask)
+        o = attend(_query(y, lp, "sa", h), new["ck"][l][:blocks],
+                   new["cv"][l][:blocks], t)
         x = x + _lin(o, lp["sa_o_w"], lp["sa_o_b"])
         y = layer_norm(x, lp["ln_ca_s"], lp["ln_ca_b"], cfg.norm_eps)
-        o = _attend_rows(_query(y, lp, "ca", h), [xk[l]], [xv[l]],
-                         jnp.zeros(xk[l].shape[1]))
+        o = attend(_query(y, lp, "ca", h), [xk[l]], [xv[l]],
+                   cfg.enc_tokens - 1)
         x = _ffn(x + _lin(o, lp["ca_o_w"], lp["ca_o_b"]), lp, cfg.norm_eps)
     logits = _logits(dec, cfg, x)
     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -519,7 +555,7 @@ def decode_step(params, cfg: NougatConfig, state: dict, xk, xv, t,
 
 
 @functools.lru_cache(maxsize=None)
-def serving_programs(cfg: NougatConfig):
+def serving_programs(cfg: NougatConfig, force_kernel: bool = False):
     """(``parse_encode``, ``parse_decode``): the jitted programs, the
     caches they write donated. ``parse_decode(params, state, xk, xv, t,
     span=...)`` takes ``span`` static: one program per cache block."""
@@ -527,7 +563,8 @@ def serving_programs(cfg: NougatConfig):
         return encode_into_slots(params, cfg, images, xk, xv, slot)
 
     def parse_decode(params, state, xk, xv, t, span):
-        return decode_step(params, cfg, state, xk, xv, t, span)
+        return decode_step(params, cfg, state, xk, xv, t, span,
+                           force_kernel)
 
     return (jax.jit(parse_encode, donate_argnums=(2, 3)),
             jax.jit(parse_decode, donate_argnums=(1,),

@@ -591,3 +591,67 @@ def test_encoder_attention_refuses_rows_longer_than_vmem_holds():
     bias = jnp.zeros((1, MAX_SEQ + 128), jnp.float32)
     with pytest.raises(ValueError, match=f"> {MAX_SEQ}"):
         encoder_attention_kernel(q, q, q, bias, interpret=True)
+
+
+# decode attention: (slots, heads, head_dim, rows a block, blocks, the
+# last position that counts, dtype, live slots)
+_DECODE_CASES = {
+    "self128_all_live": (16, 16, 64, 128, 1, 77, jnp.bfloat16,
+                         range(16)),
+    "self7_scattered": (16, 16, 64, 128, 7, 6 * 128 + 50, jnp.bfloat16,
+                        (0, 3, 4, 9, 14)),
+    "cross588_all_live": (16, 16, 64, 592, 1, 587, jnp.bfloat16, range(16)),
+    "cross588_one_live": (24, 16, 64, 592, 1, 587, jnp.bfloat16, (17,)),
+    "small_f32_scattered": (8, 4, 32, 128, 2, 150, jnp.float32, (1, 2, 6)),
+    "small_f32_none_live": (8, 4, 32, 128, 2, 150, jnp.float32, ()),
+}
+
+
+@pytest.mark.parametrize("case", list(_DECODE_CASES))
+def test_decode_attention_kernel_vs_einsum(case):
+    """The kernel against ``models.nougat._attend_rows``, its einsum
+    oracle, in interpret mode: every live slot's row to float32
+    rounding (the same f32 scores, softmax and products, summed in
+    another order; a bf16 row may round one bf16 step apart), every
+    dead slot's row zero."""
+    from repro.kernels.decode_attention import decode_attention, live_slots
+    from repro.models.nougat import NEG_INF, _attend_rows
+
+    s, h, d, rows, blocks, last, dtype, on = _DECODE_CASES[case]
+    keys = jax.random.split(jax.random.key(7), 1 + 2 * blocks)
+    q = (jax.random.normal(keys[0], (s, h, d), jnp.float32)
+         * d ** -0.5).astype(dtype)
+    ks, vs = ([jax.random.normal(k, (s, rows, h * d), jnp.float32)
+               .astype(dtype) for k in keys[1 + i::2]] for i in (0, 1))
+    live = np.zeros(s, bool)
+    live[list(on)] = True
+    got = decode_attention(q, ks, vs, live_slots(jnp.asarray(live)),
+                           jnp.int32(last))
+    assert got.shape == (s, h * d) and got.dtype == dtype
+    got = np.asarray(got, np.float32)
+    bias = jnp.where(jnp.arange(rows * blocks) <= last, 0.0, NEG_INF)
+    want = np.asarray(_attend_rows(q, ks, vs, bias), np.float32)
+    assert np.all(got[~live] == 0)
+    tol = {jnp.float32: 1e-6, jnp.bfloat16: 2.0 ** -8}[dtype]
+    np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("count", [0, 1, 5, 8, 13, 16])
+def test_decode_attention_places_keep_their_last_live_block(count):
+    """Each of a grid step's places reads the slots ``order`` gives while
+    they are live, then holds its last live slot's block (or its first,
+    if it never had a live one), so dead slots issue no DMA."""
+    from repro.kernels.decode_attention.kernel import blocks_of
+
+    group, s = 4, 16
+    order = jnp.asarray(np.random.RandomState(count).permutation(s),
+                        jnp.int32)
+    src = np.asarray(blocks_of(order, jnp.int32(count), group))
+    for j in range(group):
+        held = src[j::group]
+        live = np.arange(j, s, group) < count
+        np.testing.assert_array_equal(held[live],
+                                      np.asarray(order)[j::group][live])
+        dead = held[~live]
+        want = held[live][-1] if live.any() else int(order[j])
+        assert np.all(dead == want)

@@ -92,6 +92,45 @@ def test_decode_through_the_cache_matches_the_reference(tiny, ref,
         assert cap["tokens"][0] == cfg.bos_id
 
 
+def test_attention_kernel_decodes_as_the_einsum_path(ref):
+    """``NougatBackend`` with the decode attention kernel forced
+    (interpret mode), on the tiny preset widened to rows of whole
+    128-lane tiles (the kernel's shape rule), against the same backend
+    on the einsum path: the same steps, fed tokens and captured logits;
+    ``parse.attention_kernel`` counts every decode step of the kernel
+    path and none of the other. Two waves, the first reading two cache
+    blocks, with slots that finish early or hold no page."""
+    import dataclasses
+
+    cfg = dataclasses.replace(PM.parser_config("reduced"), dec_d_model=128)
+    widths = {f: getattr(cfg, f) for f in cfg.__dataclass_fields__
+              if f != "name"}
+    weights = ref.init(widths, 2 ** 31 + 11)
+    pages = _pages(8, [130, 3, 41, 9] + [12] * 14)
+    keys = [(0, j) for j in range(len(pages))]
+    runs = []
+    for force in (False, True):
+        be = PM.NougatBackend(cfg, weights, force_kernel=force)
+        assert be.attention_kernel == force
+        before = obs.metrics().snapshot()["counters"]
+        out = be.run(keys, pages, np.random.RandomState(4))
+        after = obs.metrics().snapshot()["counters"]
+        grew = {k: after.get(k, 0) - before.get(k, 0)
+                for k in (obs.PARSE_ATTENTION_KERNEL, "parse.decode_steps")}
+        assert grew["parse.decode_steps"] == 130 + 12
+        assert grew[obs.PARSE_ATTENTION_KERNEL] == \
+            (grew["parse.decode_steps"] if force else 0)
+        runs.append(out)
+    einsum, kernel = runs
+    assert kernel["steps"] == einsum["steps"] == [len(p) for p in pages]
+    assert len(kernel["captures"]) == len(einsum["captures"]) == 2
+    for a, b in zip(kernel["captures"], einsum["captures"]):
+        assert a["page"] == b["page"] and a["steps"] == b["steps"]
+        np.testing.assert_array_equal(a["tokens"], b["tokens"])
+        gap = np.abs(a["logits"] - b["logits"]).max(-1) / b["logits"].std(-1)
+        assert gap.max() < TOLERANCE
+
+
 def test_every_wave_encodes_every_slot(tiny):
     """A wave runs the encoder over all its slots whatever the number of
     pages it holds, and a slot no page holds gets a blank page's

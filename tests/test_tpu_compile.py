@@ -205,14 +205,18 @@ def test_published_route_step_compiles_with_kernel(one_chip,
     assert compiled.memory_analysis().temp_size_in_bytes < scores / 2
 
 
-def _nougat_program(which: str, one_chip, span: int | None = None):
+def _nougat_program(which: str, one_chip, span: int | None = None,
+                    monkeypatch=None):
     """``parse_encode`` (a chunk of pages into the slot caches) or, with
     ``span``, ``parse_decode`` (a greedy step reading ``span`` cache
     positions) at nougat-base's published widths, compiled for the
-    described chip."""
+    described chip. With ``monkeypatch`` the decode is steered to its
+    TPU branch (the decode attention kernel), as ``_route_step`` is."""
     from repro.core.parser_model import parser_config
     from repro.models import nougat
 
+    if monkeypatch is not None:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = parser_config("published")
     on_chip = lambda t: jax.tree_util.tree_map(          # noqa: E731
         lambda a: _sds(a.shape, a.dtype, one_chip), t)
@@ -252,13 +256,36 @@ def test_nougat_encode_compiles_at_published_widths(one_chip,
 
 @pytest.mark.parametrize("span", [128, 896])
 def test_nougat_decode_compiles_at_published_widths(one_chip,
-                                                    no_compile_cache, span):
+                                                    no_compile_cache,
+                                                    monkeypatch, span):
     """A decode step of nougat-base (10 layers, d 1024, 50,000 tokens)
-    over 96 slots: the self-attention cache is updated in place and
-    read where it lies, up to ``span``: the temporaries stay far below
-    one copy of the cache (3.5 GB), and the cross-attention K/V (2.3
-    GB) is read, not copied."""
-    cfg, compiled = _nougat_program("decode", one_chip, span)
+    over 96 slots, as a TPU runs it: each layer's self and cross
+    attention is the decode attention kernel, which reads the caches
+    where they lie: no copy or transpose of a self-attention block
+    (96 x 128 x 1024) or a cross-attention K/V (96 x 592 x 1024) feeds
+    it. The self-attention cache is updated in place, the temporaries
+    stay far below one copy of it (3.5 GB), and the cross-attention K/V
+    (2.3 GB) is read, not copied."""
+    from repro.models import nougat
+
+    cfg, compiled = _nougat_program("decode", one_chip, span, monkeypatch)
+    hlo = compiled.as_text()
+    calls = re.findall(r"%(decode_attention_kernel[.\d]*) = \S+ custom-call"
+                       r"\(([^)]*)\), custom_call_target=\"tpu_custom_call\"",
+                       hlo)
+    assert len(calls) == 2 * cfg.dec_layers
+    caches = [rf"bf16\[{cfg.decode_slots},{rows},{cfg.dec_d_model}\]"
+              for rows in (nougat.KV_BLOCK, nougat.cross_rows(cfg))]
+    relaid = re.compile(rf"= (?:{'|'.join(caches)})\S* (copy|transpose)\(")
+    for _, operands in calls:
+        kv = {op for op in re.sub(r"/\*[^*]*\*/", "", operands).split(", ")
+              if re.match("|".join(caches),
+                          _hlo_defines(hlo, op).split(" = ")[1])}
+        assert kv
+        for op in kv:
+            line = _hlo_defines(hlo, op)
+            assert not relaid.search(line), line
+            assert not relaid.search(_fused_ops(hlo, line)), line
     mem = compiled.memory_analysis()
     own = 2 * cfg.dec_layers * cfg.decode_slots * cfg.cache_len \
         * cfg.dec_d_model * 2
