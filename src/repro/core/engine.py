@@ -135,8 +135,7 @@ class EngineConfig:
     # prepare-stage routing-input path (core/features
     # .prepare_routing_inputs): "auto" = fused Pallas kernel on TPU /
     # fused host oracle elsewhere, "force" = kernel even off-TPU
-    # (interpret; parity tests and benches), "host" = legacy unfused
-    # numpy pipeline
+    # (interpret; parity tests)
     feature_kernel: str = "auto"
 
 

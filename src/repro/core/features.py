@@ -10,8 +10,9 @@ loops over documents in Python on its hot path.
 dispatches through: one call derives the fast features *and* (for the
 LLM router variant) the first-page token/mask pair via
 ``kernels.fast_features`` — the Pallas kernel on device backends, the
-exact packed-stream host oracle elsewhere. The legacy per-function
-pipeline below stays as the bit-for-bit reference (``mode="host"``).
+exact packed-stream host oracle elsewhere. ``batch_fast_features`` and
+``batch_first_page_tokens`` are the per-function pipeline that oracle
+reproduces bit for bit; they are its test reference, not an engine mode.
 """
 from __future__ import annotations
 
@@ -21,7 +22,7 @@ from repro.data.synthetic import MANGLED, SCRAMBLE, WS, CorpusConfig
 from repro.kernels.fast_features import ops as ff_ops
 
 N_FAST_FEATURES = 8
-FEATURE_KERNEL_MODES = ("auto", "force", "host")
+FEATURE_KERNEL_MODES = ("auto", "force")
 
 
 def batch_fast_features(page_lists, cfg: CorpusConfig) -> np.ndarray:
@@ -106,21 +107,13 @@ def prepare_routing_inputs(page_lists, cfg: CorpusConfig, *,
 
     ``mode`` (``EngineConfig.feature_kernel``): "auto" dispatches the
     Pallas fast_features kernel on TPU and the packed host oracle
-    elsewhere (bit-identical to the legacy pipeline, minus the
-    composite-key sort); "force" runs the kernel even off-TPU
-    (interpret — parity tests and benches); "host" is the legacy
-    unfused ``batch_fast_features`` + ``batch_first_page_tokens``
-    pipeline.
+    elsewhere (bit-identical to ``batch_fast_features`` +
+    ``batch_first_page_tokens``, minus the composite-key sort); "force"
+    runs the kernel even off-TPU (interpret — parity tests).
     """
     if mode not in FEATURE_KERNEL_MODES:
         raise ValueError(f"feature_kernel mode {mode!r} not in "
                          f"{FEATURE_KERNEL_MODES}")
-    if mode == "host":
-        fast = batch_fast_features(page_lists, cfg)
-        if max_len is None:
-            return fast, None, None
-        toks, mask = batch_first_page_tokens(page_lists, max_len)
-        return fast, toks, mask
     packed = ff_ops.pack_routing_batch(page_lists,
                                        max_len=int(max_len or 0))
     return ff_ops.routing_features(

@@ -16,10 +16,8 @@ with length masks — the hot path of the online quality probe
 (core/quality), which scores sampled campaign batches at round
 granularity. BLEU dispatches through the fused n-gram op
 (kernels/ngram_score: Pallas equality-matrix kernel on TPU, sorted
-n-gram multisets on CPU); the old jitted pairwise matcher is kept as
-``_bleu_batch``, the baseline the ``engine.score_kernel_speedup`` bench
-measures against. ``rouge_l`` and ``car`` are thin corpus-mean wrappers
-over it.
+n-gram multisets on CPU). ``rouge_l`` and ``car`` are thin corpus-mean
+wrappers over it.
 """
 from __future__ import annotations
 
@@ -149,53 +147,6 @@ def _edit_distance_batch(a: jax.Array, b: jax.Array, la: jax.Array,
             jnp.where(lb1 > 0, 0, la1)
 
     return jax.vmap(one)(a, b, la, lb)
-
-
-@functools.partial(jax.jit, static_argnames=("max_len", "max_n"))
-def _bleu_batch(ref: jax.Array, hyp: jax.Array, lr: jax.Array,
-                lh: jax.Array, max_len: int, max_n: int = 4) -> jax.Array:
-    """Batched sentence BLEU on padded id sequences (uniform n<=max_n
-    weights, brevity penalty, 1e-9 smoothing — the same rule as the host
-    ``bleu``, truncated to ``max_len`` tokens).
-
-    Superseded on the probe hot path by kernels/ngram_score (same
-    clipped-count rule, fused); kept as the XLA baseline that
-    ``engine.score_kernel_speedup`` is measured against.
-
-    Clipped counts without Counters: hyp occurrence j of an n-gram g is
-    creditable iff its occurrence rank among equal hyp grams is below
-    g's count in the reference — both ranks come from pairwise n-gram
-    equality matrices, built incrementally (an (n+1)-gram match is an
-    n-gram match AND a token match one position later)."""
-    smooth = 1e-9
-    pos = jnp.arange(max_len)
-
-    def one(r1, h1, lr1, lh1):
-        eq_hh = h1[:, None] == h1[None, :]
-        eq_hr = h1[:, None] == r1[None, :]
-        m_hh, m_hr = eq_hh, eq_hr
-        log_p = jnp.float32(0.0)
-        for n in range(1, max_n + 1):
-            if n > 1:
-                # extend (n-1)-gram matches by the token at offset n-1
-                w = max_len - (n - 1)
-                m_hh = m_hh & jnp.zeros_like(eq_hh).at[:w, :w].set(
-                    eq_hh[n - 1:, n - 1:])
-                m_hr = m_hr & jnp.zeros_like(eq_hr).at[:w, :w].set(
-                    eq_hr[n - 1:, n - 1:])
-            ph = pos <= lh1 - n          # valid hyp n-gram starts
-            pr = pos <= lr1 - n
-            total = jnp.maximum(lh1 - n + 1, 0)
-            # per-hyp-gram reference count and prior-occurrence rank
-            rc = jnp.sum(m_hr & pr[None, :], axis=1)
-            occ = jnp.sum(jnp.tril(m_hh, -1) & ph[None, :], axis=1)
-            clipped = jnp.sum(ph & (occ < rc))
-            log_p += jnp.log((clipped + smooth) / jnp.maximum(total, 1))
-        log_p /= max_n
-        bp = jnp.minimum(1.0, jnp.exp(1.0 - lr1 / jnp.maximum(lh1, 1)))
-        return jnp.where(lh1 > 0, bp * jnp.exp(log_p), 0.0)
-
-    return jax.vmap(one)(ref, hyp, lr, lh)
 
 
 def _pad_batch(seqs: list[np.ndarray], max_len: int):

@@ -134,17 +134,3 @@ def parse_cost_batch(name: str, docs: list[Document]) -> np.ndarray:
     dispatched through the backend registry."""
     from repro.core import backends
     return backends.get_backend(name).cost_batch(docs)
-
-
-def throughput_at_nodes(name: str, n_nodes: int,
-                        fs_bandwidth_Bps: float = 650e9,
-                        doc_bytes: float | None = None) -> float:
-    """Fig. 5 scaling model: linear in nodes, capped by (a) a backend's
-    internal scale ceiling and (b) shared-filesystem bandwidth."""
-    from repro.core import backends
-    info = backends.get_backend(name).info
-    eff_nodes = min(n_nodes, info.scale_cap_nodes)
-    linear = info.pdf_per_sec_node * eff_nodes
-    io = (doc_bytes or info.io_bytes_per_doc)
-    fs_cap = fs_bandwidth_Bps / io * 0.001   # ~0.1% of agg BW per campaign
-    return min(linear, fs_cap)

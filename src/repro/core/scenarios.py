@@ -45,10 +45,8 @@ Eight shipped scenarios (``SCENARIOS``):
                            duplicate replies through generation-tagged
                            arena slots
 
-``benchmarks/bench_scenarios.py`` sweeps the registry into
-``BENCH_scenarios.json``; ``serve.py --scenario NAME`` reproduces any
-one from the CLI; ``tests/test_scenarios.py`` runs the fast subset in
-tier-1.
+``tests/test_scenarios.py`` runs every scenario end to end in tier-1;
+``serve.py --scenario NAME`` reproduces any one from the CLI.
 """
 from __future__ import annotations
 
@@ -134,16 +132,16 @@ class ScenarioSpec:
 
 @dataclasses.dataclass
 class ScenarioResult:
-    """Per-scenario counters recorded into BENCH_scenarios.json. A
-    result is only ever constructed after the determinism invariant
-    held (``run_scenario`` raises ``ScenarioMismatch`` otherwise)."""
+    """Per-scenario counters (``metrics()``). A result is only ever
+    constructed after the determinism invariant held (``run_scenario``
+    raises ``ScenarioMismatch`` otherwise)."""
 
     name: str
     runtime: str
     n_nodes: int
     n_docs: int
-    records_match: bool               # asserted True; recorded for the
-    wall_s: float                     # bench artifact's per-scenario keys
+    records_match: bool               # asserted True
+    wall_s: float
     goodput_docs_per_s: float
     reissued: int
     reissued_reparse: int
@@ -443,11 +441,6 @@ _SPECS = (
 )
 
 SCENARIOS: dict[str, ScenarioSpec] = {s.name: s for s in _SPECS}
-
-#: Scenarios cheap enough for tier-1 (no process spawns): the local
-#: simulated fleet end-to-end. The process scenarios run in the bench
-#: sweep and the CI fast lane.
-FAST_SCENARIOS = ("bursty_arrivals", "bimodal_retune", "slowdown_skew")
 
 
 def get_scenario(name: str) -> ScenarioSpec:

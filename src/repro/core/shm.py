@@ -2,9 +2,8 @@
 
 Batch payloads — ingest documents, forwarded ``PreparedBatch``/plan
 pairs, and result records — are numpy-array-heavy: pickling them through
-the multiprocessing queues copies every page twice (dumps + pipe) and
-was the measured ~3.5% per-batch overhead bounding
-``engine.mp_wall_speedup``. This module moves the bulk bytes through
+the multiprocessing queues copies every page twice (dumps + pipe). This
+module moves the bulk bytes through
 ``multiprocessing.shared_memory`` segments instead, leaving the
 ``PrepareTask``/``CompleteTask``/``BatchDone`` dataclasses as
 control-plane messages only: a message carries a small ``ShmRef``

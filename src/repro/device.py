@@ -6,8 +6,8 @@
   at the checkout root (derived from this package's path, never from a
   temporary name, pid or time: the path is part of the cache key, so a
   moving directory never hits). Called first by ``serve.main``,
-  ``chip_smoke.py``, ``benchmarks/run.main``, ``launch/train.py`` and
-  the standalone fabric worker (``serve --connect``).
+  ``benchmarks/run.main``, ``launch/train.py`` and the standalone
+  fabric worker (``serve --connect``).
 - ``refuse_chip_children``: a TPU belongs to one process at a time. The
   process and loopback-fabric runtimes spawn children that each build a
   full engine (jitted route step included); under a parent that holds

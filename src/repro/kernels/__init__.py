@@ -1,6 +1,7 @@
 """TPU Pallas kernels for the compute hot-spots:
 
-- flash_attention : Nougat/LM attention (the ViT inference hot loop)
+- flash_attention : causal/windowed LM attention; runs on no parsing
+                    path (Nougat uses decode_attention)
 - encoder_attention: the router encoder's bidirectional attention in
                     the route step (a whole row of keys in VMEM)
 - decode_attention : Nougat's greedy decode step, one query row per page

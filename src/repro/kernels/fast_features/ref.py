@@ -9,8 +9,7 @@ oracle the Pallas kernel is tested against (1e-6) and the CPU dispatch
 path of ``routing_features`` — it works on the flat stream in O(T)
 (bincount segment sums, like the legacy path) but swaps the legacy
 O(T log T) composite-key sort for an O(T + n·V) presence bitmap and
-fuses the first-page token/mask assembly into the same pass, which is
-where the host-side ``feature_kernel_speedup`` comes from.
+fuses the first-page token/mask assembly into the same pass.
 
 Takes plain numeric token-space parameters (no ``CorpusConfig``):
 kernels must not depend on core — core imports kernels, not the

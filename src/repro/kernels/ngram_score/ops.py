@@ -4,10 +4,9 @@
 batch of (reference, hypothesis) token streams per document. On TPU the
 Pallas kernel keeps the pairwise equality matrices in VMEM; elsewhere it
 dispatches to the sorted-multiset numpy oracle (ref.py), which is both
-the exact float64 mirror of the host ``metrics.bleu`` rule and an
-O(L log L) replacement for the old XLA O(L^2) pairwise path — the
-``engine.score_kernel_speedup`` bench measures that win at probe batch
-shapes. ``force_kernel`` runs the kernel in interpret mode (CI parity).
+the exact float64 mirror of the host ``metrics.bleu`` rule and
+O(L log L) per document. ``force_kernel`` runs the kernel in interpret
+mode (CI parity).
 """
 from __future__ import annotations
 

@@ -15,9 +15,8 @@ once — run boundaries in the sorted values delimit the (doc, gram)
 groups, per-stream bincounts over the group ranks give the clipped
 counts, and the ranks scattered back are the dense ids the next order
 extends. ~1 argsort per order over ~2·B·L elements total, instead of
-byte-wise void sorts per document. This is the fast CPU dispatch
-target of ``ops.ngram_bleu`` and the ``engine.score_kernel_speedup``
-win over the old XLA pairwise path.
+byte-wise void sorts per document. This is the CPU dispatch target of
+``ops.ngram_bleu``.
 
 ``_doc_bleu`` keeps the simple one-document factorization as the
 oracle's oracle (tests pit the batched counts against it).

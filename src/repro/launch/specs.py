@@ -3,8 +3,7 @@
 Every one of the 40 assigned cells (+ the paper's own router/parser cells)
 is materialized here as a jit-able step function plus weak-type-correct
 ShapeDtypeStruct stand-ins for all inputs (parameters, optimizer state,
-batches, KV caches) — the dry-run lowers exactly these objects, so no
-full-size array is ever allocated.
+batches, KV caches), so lowering a cell allocates no full-size array.
 """
 from __future__ import annotations
 
@@ -602,15 +601,3 @@ def _reduce_shape(family: str, shape: ShapeConfig) -> ShapeConfig:
         if "n_candidates" in d:
             d["n_candidates"] = min(d["n_candidates"], 64)
     return ShapeConfig(shape.name, shape.kind, d, shape.note)
-
-
-def all_cells() -> list[tuple[str, str]]:
-    """Every (arch, runnable shape) pair — the 40-cell matrix (minus
-    documented skips) + the paper's own cells."""
-    from repro.configs import list_archs
-    out = []
-    for a in list_archs():
-        arch = get_config(a)
-        for s in arch.runnable_shapes():
-            out.append((a, s.name))
-    return out

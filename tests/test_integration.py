@@ -85,6 +85,64 @@ def test_serve_driver_end_to_end():
     assert res["coverage"] > 0.8
 
 
+def test_serve_llm_variant_end_to_end(monkeypatch):
+    """serve --variant llm (reduced router) through the adaptive
+    controller and the quality probe: 144 documents leave a 96-document
+    test split in 3 route batches of 32. One record per test document,
+    the expensive share within alpha, at least one probed batch, and the
+    campaign's plan for the first batch equal to scheduler.plan_batch
+    on that route step's own improvement output."""
+    from repro.core import scheduler
+    from repro.core.engine import AdaParseEngine
+    from repro.core.quality import QualityProbe
+    from repro.launch.serve import main as serve_main
+
+    routed, probed, evaluated = [], [], []
+    device_plan = AdaParseEngine._device_plan
+    score_records = QualityProbe.score_records
+    evaluate = AdaParseEngine.evaluate
+
+    def device_plan_seen(self, prep):
+        plan = device_plan(self, prep)
+        if not routed:
+            out = self._route_step(self.router.enc_params,
+                                   prep.route_host["tokens"],
+                                   prep.route_host["mask"],
+                                   prep.route_host["valid_logit"])
+            idx = np.asarray(out["selected_idx"])
+            host = scheduler.plan_batch(np.asarray(out["improvement"]),
+                                        self.cfg.alpha)
+            np.testing.assert_array_equal(np.sort(idx[idx >= 0]),
+                                          host.expensive_idx)
+            np.testing.assert_array_equal(plan.expensive_idx,
+                                          host.expensive_idx)
+        routed.append(len(prep.docs))
+        return plan
+
+    def score_records_seen(self, docs, records):
+        probed.append(len(docs))
+        return score_records(self, docs, records)
+
+    def evaluate_seen(self, docs, records):
+        evaluated.append(({d.doc_id for d in docs}, set(records)))
+        return evaluate(self, docs, records)
+
+    monkeypatch.setattr(AdaParseEngine, "_device_plan", device_plan_seen)
+    monkeypatch.setattr(QualityProbe, "score_records", score_records_seen)
+    monkeypatch.setattr(AdaParseEngine, "evaluate", evaluate_seen)
+    res = serve_main(["--variant", "llm", "--docs", "144",
+                      "--batch-size", "32", "--alpha", "0.1",
+                      "--adaptive-rounds", "2",
+                      "--quality-probe-rate", "0.5"])
+    assert routed == [32, 32, 32]
+    assert len(evaluated) == 1
+    test_ids, record_ids = evaluated[0]
+    assert len(test_ids) == 96 and record_ids == test_ids
+    assert res["frac_expensive"] <= 0.1 + 1e-9
+    assert probed and sum(probed) > 0
+    assert all(np.isfinite(v) for v in res.values())
+
+
 def test_serve_validates_cli_arguments(capsys):
     """Malformed --pools / --prefetch-depth specs exit with an
     actionable argparse error instead of a traceback from deep inside

@@ -435,23 +435,26 @@ def test_fast_features_kernel_vs_ref(seed, max_len, block_l):
 
 
 def test_fast_features_engine_force_mode_matches_host():
-    """prepare_batch in feature_kernel='force' (interpret kernel)
-    produces routing inputs matching the host path: tokens/mask exact,
-    features to 1e-6."""
+    """prepare_routing_inputs in feature_kernel='force' (interpret
+    kernel) matches the host reference ``batch_fast_features`` +
+    ``batch_first_page_tokens``: tokens/mask exact, features to 1e-6.
+    Only "auto" and "force" are modes; "host" raises."""
     from repro.core import features as F
     from repro.data.synthetic import CorpusConfig
 
     cfg = CorpusConfig()
     pls = _page_batch(30, 5, vocab=cfg.vocab_size)
-    hf, ht, hm = F.prepare_routing_inputs(pls, cfg, max_len=24,
-                                          mode="host")
+    hf = F.batch_fast_features(pls, cfg)
+    ht, hm = F.batch_first_page_tokens(pls, 24)
     kf, kt, km = F.prepare_routing_inputs(pls, cfg, max_len=24,
                                           mode="force")
     np.testing.assert_allclose(np.asarray(kf, np.float64), hf, atol=1e-6)
     np.testing.assert_array_equal(np.asarray(kt), ht)
     np.testing.assert_array_equal(np.asarray(km), hm)
-    with pytest.raises(ValueError, match="feature_kernel"):
-        F.prepare_routing_inputs(pls, cfg, mode="gpu")
+    assert F.FEATURE_KERNEL_MODES == ("auto", "force")
+    for mode in ("host", "gpu"):
+        with pytest.raises(ValueError, match="feature_kernel"):
+            F.prepare_routing_inputs(pls, cfg, mode=mode)
 
 
 @pytest.mark.parametrize("e,n,din,dout", [

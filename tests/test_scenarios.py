@@ -1,19 +1,16 @@
 """The scenario lab (core/scenarios): a named, deterministic stress-
-scenario matrix over both worker runtimes. Tier-1 runs the fast subset
-(the local simulated fleet) end-to-end — each scenario asserts the
+scenario matrix over the local, process and fabric worker runtimes.
+Tier-1 runs every scenario end-to-end — each asserts the
 byte-identical-records invariant against its single-node reference
-inside ``run_scenario`` — plus the registry/runner contracts. The
-process-runtime scenarios run in the bench sweep (BENCH_scenarios.json)
-and the CI fast lane."""
+inside ``run_scenario`` — plus the registry/runner contracts."""
 import dataclasses
 
 import numpy as np
 import pytest
 
-from repro.core.scenarios import (FAST_SCENARIOS, SCENARIOS,
-                                  ScenarioMismatch, ScenarioSpec,
-                                  _assert_records_match, get_scenario,
-                                  run_scenario)
+from repro.core.scenarios import (SCENARIOS, ScenarioMismatch,
+                                  ScenarioSpec, _assert_records_match,
+                                  get_scenario, run_scenario)
 
 REQUIRED = {"crash_storm", "wedged_straggler_flap", "bursty_arrivals",
             "bimodal_retune", "cold_warm_shared_store", "slowdown_skew",
@@ -23,8 +20,7 @@ REQUIRED = {"crash_storm", "wedged_straggler_flap", "bursty_arrivals",
 def test_registry_ships_the_scenario_matrix():
     """At least the six ISSUE-6 scenarios plus the shm-transport crash
     scenario and the elastic fabric scenario, each fully declarative
-    and self-describing; the fast subset is a strict subset that avoids
-    process spawns."""
+    and self-describing."""
     assert REQUIRED <= set(SCENARIOS)
     assert len(SCENARIOS) >= 8
     for name, spec in SCENARIOS.items():
@@ -39,8 +35,6 @@ def test_registry_ships_the_scenario_matrix():
     assert elastic.fault is not None       # the mid-campaign crash
     assert elastic.fabric is not None      # the join + reject schedule
     assert elastic.fabric.join_after and elastic.fabric.reject >= 1
-    assert set(FAST_SCENARIOS) <= set(SCENARIOS)
-    assert all(SCENARIOS[n].runtime == "local" for n in FAST_SCENARIOS)
 
 
 def test_get_scenario_unknown_name_is_actionable():
@@ -48,13 +42,14 @@ def test_get_scenario_unknown_name_is_actionable():
         get_scenario("no_such_scenario")
 
 
-@pytest.mark.parametrize("name", FAST_SCENARIOS)
+@pytest.mark.parametrize("name", SCENARIOS)
 def test_fast_scenarios_end_to_end(name):
-    """Each fast scenario runs its fleet, survives its adversarial
-    schedule, and reproduces the single-node reference byte-for-byte
+    """Every scenario, on whichever runtime it names (local, process or
+    loopback fabric), runs its fleet, survives its adversarial schedule,
+    and reproduces the single-node reference byte-for-byte
     (run_scenario raises ScenarioMismatch otherwise)."""
     res = run_scenario(SCENARIOS[name])
-    assert res.records_match
+    assert res.records_match and res.runtime == SCENARIOS[name].runtime
     assert res.n_docs > 0 and res.goodput_docs_per_s > 0
     m = res.metrics()
     for key in ("records_match", "goodput_docs_per_s", "reissued",
