@@ -76,12 +76,15 @@ STAGE_HISTOGRAMS = {"prepare": "engine.prepare_s", "route": "engine.route_s",
 #: (``engine.route_batch``, device path), so a run shows it engaged
 ROUTE_ATTENTION_KERNEL = "route.attention_kernel"
 
-#: counters of a device parser's decode (``core/parser_model``, always
-#: on): pages parsed; decode steps; slots times steps; steps of slots
-#: whose page was still decoding; and the sum, over those live steps,
-#: of the page's cache length (its position + 1)
+#: counters of a device parser (``core/parser_model``, always on): pages
+#: parsed; decode steps; slots times steps; steps of slots whose page
+#: was still decoding; the sum, over those live steps, of the page's
+#: cache length (its position + 1); and pages the encoder ran, the blank
+#: pages that fill out a wave's last chunk included (so
+#: ``1 - parse.pages / parse.encoded_pages`` is the blank share)
 PARSE_COUNTERS = ("parse.pages", "parse.decode_steps", "parse.slot_steps",
-                  "parse.live_slot_steps", "parse.live_kv_positions")
+                  "parse.live_slot_steps", "parse.live_kv_positions",
+                  "parse.encoded_pages")
 
 #: counter: decode steps of a device parser whose attention ran the
 #: decode attention kernel (``core/parser_model``, always on), so a run

@@ -11,23 +11,25 @@ One ``parse_batch``:
 2. Every page of every selected document is drawn as an image
    (``models.nougat.page_images``; span ``parse.render``).
 3. In waves of ``decode_slots`` pages (one wave where the batch's pages
-   fit): the encoder runs over every slot, in chunks of
-   ``nougat.ENCODE_CHUNK`` pages, a blank page in each slot no page
-   holds, and writes each slot's cross-attention K/V
-   (``parse.encode``); then every slot decodes greedily from BOS at
-   once, each page for exactly its own token count, the cache read a
-   ``nougat.KV_BLOCK`` at a time (``parse.decode``). Both end blocked
-   on the device (``parse.wait``). So a wave's device time does not
-   depend on how many pages it holds: it lasts as long as its longest
-   page, and slots whose page has finished, or that hold none, still
-   run.
+   fit): the encoder runs over the wave's pages only, in chunks of
+   ``nougat.ENCODE_CHUNK`` (the last filled out with blank pages), and
+   writes each page's cross-attention K/V into its slot
+   (``parse.encode``); slots past the last chunk keep whatever K/V
+   they held. Then every slot decodes greedily from BOS at once, each
+   page for exactly its own token count, the cache read a
+   ``nougat.KV_BLOCK`` at a time (``parse.decode``); a slot no page
+   holds decodes for 0 steps, so it is dead the whole wave. Both end
+   blocked on the device (``parse.wait``). The encode follows the
+   wave's page count; the decode lasts as long as its longest page,
+   and slots whose page has finished, or that hold none, still step.
 4. ``last`` keeps what the batch decoded, for a check: each page's
    decoded step count, and at seeded (page, step) pairs (2 pages, 8
    steps each, among them each page's first and last) the logits the
    decode produced and the tokens it fed.
 
 Counters (``obs.PARSE_COUNTERS``) count the decode's pages, steps, slot
-steps, live slot steps and live cache positions;
+steps, live slot steps and live cache positions, and the pages the
+encoder ran, blank padding included;
 ``obs.PARSE_ATTENTION_KERNEL`` the steps whose attention ran
 ``kernels.decode_attention``, which reads live slots' K/V only (a TPU,
 or ``force_kernel``).
@@ -132,7 +134,7 @@ class NougatBackend:
         with obs.span("parse.render", None):
             images = nougat.page_images(pages, cfg)
             images = np.concatenate([images, np.full(
-                (slots - len(images),) + images.shape[1:], 255, np.uint8)])
+                (-len(images) % chunk,) + images.shape[1:], 255, np.uint8)])
         c = self.caches
         with obs.span("parse.encode", None):
             for i in range(0, len(images), chunk):
@@ -154,7 +156,7 @@ class NougatBackend:
                 steps, tokens, logits = jax.device_get(
                     (state["steps"], state["tokens"], state["cap"]))
         counts = (len(lengths), t_max, slots * t_max, int(lengths.sum()),
-                  int((lengths * (lengths + 1) // 2).sum()))
+                  int((lengths * (lengths + 1) // 2).sum()), len(images))
         for name, n in zip(obs.PARSE_COUNTERS, counts):
             obs.metrics().count(name, n)
         if self.attention_kernel:

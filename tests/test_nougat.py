@@ -3,6 +3,7 @@ slot caches, greedy steps through the self-attention cache) against
 the benchmark's plain float32 reference, the Swin window mask and
 relative-position index against their brute-force forms, and the
 device backend inside the engine."""
+import dataclasses
 import importlib.util
 import pathlib
 
@@ -68,9 +69,8 @@ def test_decode_through_the_cache_matches_the_reference(tiny, ref,
     """Every step of two pages (all their steps captured), the decode's
     logits against the reference's full forward pass teacher-forced on
     the tokens the decode fed; each page decoded for exactly its token
-    count. Every wave encodes both chunks of the 16 slots, blank pages
-    in the slots it leaves empty; the first case reads two cache
-    blocks, the second takes two waves."""
+    count. The first case takes one encode chunk and two cache blocks;
+    the second two waves, the first of two chunks."""
     cfg, widths, weights = tiny
     monkeypatch.setattr(PM, "CAPTURE_STEPS", 200)
     be = PM.NougatBackend(cfg, weights)
@@ -100,12 +100,7 @@ def test_attention_kernel_decodes_as_the_einsum_path(ref):
     ``parse.attention_kernel`` counts every decode step of the kernel
     path and none of the other. Two waves, the first reading two cache
     blocks, with slots that finish early or hold no page."""
-    import dataclasses
-
-    cfg = dataclasses.replace(PM.parser_config("reduced"), dec_d_model=128)
-    widths = {f: getattr(cfg, f) for f in cfg.__dataclass_fields__
-              if f != "name"}
-    weights = ref.init(widths, 2 ** 31 + 11)
+    cfg, weights = _widened(ref)
     pages = _pages(8, [130, 3, 41, 9] + [12] * 14)
     keys = [(0, j) for j in range(len(pages))]
     runs = []
@@ -131,12 +126,26 @@ def test_attention_kernel_decodes_as_the_einsum_path(ref):
         assert gap.max() < TOLERANCE
 
 
-def test_every_wave_encodes_every_slot(tiny):
-    """A wave runs the encoder over all its slots whatever the number of
-    pages it holds, and a slot no page holds gets a blank page's
-    cross-attention K/V, not what the wave before left there."""
-    cfg, _, weights = tiny
-    be = PM.NougatBackend(cfg, weights)
+def _widened(ref):
+    """The tiny preset widened to rows of whole 128-lane tiles (the
+    decode attention kernel's shape rule), and its seeded weights."""
+    cfg = dataclasses.replace(PM.parser_config("reduced"), dec_d_model=128)
+    widths = {f: getattr(cfg, f) for f in cfg.__dataclass_fields__
+              if f != "name"}
+    return cfg, ref.init(widths, 2 ** 31 + 11)
+
+
+@pytest.mark.parametrize("force", [False, True], ids=["einsum", "kernel"])
+def test_a_wave_encodes_only_its_pages(ref, force):
+    """A wave runs the encoder over its own pages, in whole chunks: one
+    call for a 1-page wave, two for a full 16-slot one. The slots past
+    the last chunk keep what they held, and no step reads it: with
+    every slot's cross-attention K/V set to NaN first, a 3-page wave
+    decodes its pages exactly as a fresh backend does, on the einsum
+    path and through the decode attention kernel (interpret mode)."""
+    cfg, weights = _widened(ref)
+    chunk = nougat.ENCODE_CHUNK
+    be = PM.NougatBackend(cfg, weights, force_kernel=force)
     calls = []
     encode = be.encode
 
@@ -144,27 +153,32 @@ def test_every_wave_encodes_every_slot(tiny):
         calls.append(args[1].shape[0])
         return encode(*args)
     be.encode = counted
+    be.run([(1, 0)], _pages(6, [9]), np.random.RandomState(0))
+    assert calls == [chunk]
+    calls.clear()
     full = _pages(5, [12] * cfg.decode_slots)
     be.run([(0, j) for j in range(len(full))], full,
            np.random.RandomState(0))
-    assert sum(calls) == cfg.decode_slots
+    assert calls == [chunk] * (cfg.decode_slots // chunk)
+
+    pages = _pages(7, [20, 3, 9])
+    keys = [(2, j) for j in range(len(pages))]
+    # every slot NaN: the wave's chunk overwrites slots 0-7, 8-15 stay
+    for name in ("xk", "xv"):
+        be.caches[name] = [jnp.full_like(a, jnp.nan)
+                           for a in be.caches[name]]
     calls.clear()
-    be.run([(1, 0)], _pages(6, [9]), np.random.RandomState(0))
-    assert calls == [nougat.ENCODE_CHUNK] * (cfg.decode_slots
-                                             // nougat.ENCODE_CHUNK)
-    # float32 both ways; a chunk of eight and a lone page may round
-    # apart in the last bits, a stale slot differs at order one
-    blank = np.full((1,) + tuple(cfg.image_hw), 255, np.uint8)
-    k, v = nougat.cross_kv(weights, cfg, nougat.encode(weights, cfg, blank))
-    for layer in range(cfg.dec_layers):
-        np.testing.assert_allclose(np.asarray(be.caches["xk"][layer][1:]),
-                                   np.broadcast_to(
-                                       np.asarray(k[layer]),
-                                       be.caches["xk"][layer][1:].shape),
-                                   rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(be.caches["xv"][layer][-1]),
-                                   np.asarray(v[layer][0]),
-                                   rtol=1e-5, atol=1e-5)
+    got = be.run(keys, pages, np.random.RandomState(1))
+    assert calls == [chunk]
+    want = PM.NougatBackend(cfg, weights, force_kernel=force).run(
+        keys, pages, np.random.RandomState(1))
+    assert got["steps"] == want["steps"] == [len(p) for p in pages]
+    assert len(got["captures"]) == len(want["captures"]) == 2
+    for a, b in zip(got["captures"], want["captures"]):
+        assert a["page"] == b["page"] and a["steps"] == b["steps"]
+        np.testing.assert_array_equal(a["tokens"], b["tokens"])
+        assert np.isfinite(a["logits"]).all()
+        np.testing.assert_array_equal(a["logits"], b["logits"])
 
 
 def test_teacher_forced_forward_matches_the_reference(tiny, ref):
@@ -285,3 +299,8 @@ def test_engine_records_equal_the_channel_backends(corpus, ft_router):
                                                // 2).sum()
     assert grew["parse.slot_steps"] == \
         be.cfg.decode_slots * grew["parse.decode_steps"]
+    slots, chunk = be.cfg.decode_slots, nougat.ENCODE_CHUNK
+    waves = [min(slots, len(last["lengths"]) - w0) for _, last in decoded
+             for w0 in range(0, len(last["lengths"]), slots)]
+    assert grew["parse.encoded_pages"] == chunk * sum(-(-w // chunk)
+                                                      for w in waves)
